@@ -139,4 +139,7 @@ def main(fast: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -38,8 +38,7 @@ device-resident ClientBank — several times faster per round (see
 BENCH_fl.json) and equal to the legacy loop to f32 tolerance;
 ``legacy`` is the per-device oracle loop.  ``--pallas-agg`` sets
 ``FLConfig.use_pallas``: the batched engine then aggregates through the
-fused dequant+aggregate Pallas kernel instead of the XLA einsum
-(interpret mode on CPU, Mosaic on TPU).
+fused dequant+aggregate Pallas kernel instead of the XLA einsum.
 
 ``--horizon scan`` (``FLConfig.horizon``) runs the whole horizon as ONE
 ``lax.scan`` device program instead of dispatching round by round —
@@ -230,4 +229,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -11,6 +11,9 @@ import numpy as np
 from repro.config import FLConfig
 from repro.core import channel, fl
 from repro.data import dirichlet_partition, make_mnist_like
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 M, K, T = 30, 3, 6
 

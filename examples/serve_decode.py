@@ -10,6 +10,9 @@ import argparse
 from repro.launch.serve import main as serve_main
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="zamba2-7b")
     ap.add_argument("--batch", type=int, default=4)

@@ -14,6 +14,9 @@ import sys
 from repro.launch.train import main as train_main
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x22b")
     ap.add_argument("--steps", type=int, default=30)

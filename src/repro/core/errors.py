@@ -68,3 +68,17 @@ ERR_SCAN_ONLINE_MAPEL = (
     "and cannot run inside the traced round body; use "
     "power_mode='max' (or 'ota-align' under uplink='ota')"
 )
+
+# --- device placement (fl.run_cell_sweep, rates_jax.greedy_rounds_fused) ---
+
+ERR_SHARDS_EXCEED_DEVICES = (
+    "{option}={requested} asks for {requested} devices, but only "
+    "{available} local devices are present"
+)
+
+# --- Pallas kernels (kernels.platform) --------------------------------------
+
+ERR_PALLAS_PLATFORM = (
+    "Pallas kernels run on 'tpu' (Mosaic) or 'cpu' (interpreter), not on "
+    "{platform!r}; pass interpret= explicitly or use the XLA path"
+)

@@ -1253,8 +1253,9 @@ def run_cell_sweep(
     doesn't).
 
     With ``cell_shards > 1`` the stacked (C, S, ...) program runs under
-    ``shard_map`` over :func:`repro.launch.mesh.cell_mesh` (clamped to
-    ``jax.local_device_count()``), C padded up to a multiple of the mesh
+    ``shard_map`` over :func:`repro.launch.mesh.cell_mesh` (more shards
+    than local devices raise ``errors.ERR_SHARDS_EXCEED_DEVICES``; nothing
+    is clamped in silence), C padded up to a multiple of the mesh
     (repeating leading cells, unpadded on return) — each mesh device runs
     its own block of vmapped horizons in parallel.  On a trivial 1-device
     mesh (the default) the sweep instead dispatches one
@@ -1276,9 +1277,12 @@ def run_cell_sweep(
     if C < 1 or S < 1:
         raise ValueError(f"need num_cells >= 1 and seeds_per_cell >= 1, "
                          f"got ({num_cells}, {seeds_per_cell})")
-    shards_n = 1 if cell_shards is None else max(
-        1, min(int(cell_shards), jax.local_device_count())
-    )
+    shards_n = 1 if cell_shards is None else max(1, int(cell_shards))
+    if shards_n > jax.local_device_count():
+        raise ValueError(errors.ERR_SHARDS_EXCEED_DEVICES.format(
+            option="cell_shards", requested=shards_n,
+            available=jax.local_device_count(),
+        ))
 
     inst_seeds = [[cfg.seed + c * S + s for s in range(S)] for c in range(C)]
     if (scheduling.policy_is_online(cfg.scheduler)

@@ -23,7 +23,7 @@ whole round body into a single jitted step over a device-resident
      by default, or (``FLConfig.use_pallas``) through the fused
      dequant+aggregate Pallas kernel
      ``repro.kernels.aggregate.weighted_aggregate_pallas`` with per-client
-     levels (interpret mode on CPU, Mosaic on TPU).
+     levels.
 
 Scheduling, power allocation, rate/budget computation, timing, and logging
 stay in the :mod:`repro.core.fl` driver and are shared with the legacy
@@ -513,7 +513,6 @@ def _sharded_horizon_fn(
     sharded; the client bank / test set are replicated and the cells never
     communicate — each mesh device runs its own (C/shards, S) block of
     vmapped horizons."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import cell_mesh
@@ -540,11 +539,11 @@ def _sharded_horizon_fn(
 
         return jax.vmap(per_cell)(params_cs, dev, bud, agg, gains, keys, eidx)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=rules.cell_sweep_in_specs(),
         out_specs=rules.cell_sweep_out_specs(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -762,8 +761,6 @@ def _sharded_online_fn(
     :func:`_sharded_horizon_fn` with the online core and its operand list
     (solo tables + channel rows instead of precomputed schedule tensors;
     the shared data weights/sizes replicated like the bank)."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.launch.mesh import cell_mesh
     from repro.sharding import rules
 
@@ -790,11 +787,11 @@ def _sharded_online_fn(
 
         return jax.vmap(per_cell)(params_cs, solo, gains, keys, eidx)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=rules.cell_sweep_online_in_specs(),
         out_specs=rules.cell_sweep_online_out_specs(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 

@@ -43,12 +43,13 @@ path, in three layers:
       * ``scorer="xla"`` (default) scores vertices with
         :func:`weighted_rates_cmp`; ``scorer="pallas"`` lowers the same
         O(K^2) comparison-matrix math through the Pallas SIC kernel
-        (``repro.kernels.sic_rates``, ``interpret=True`` on CPU, Mosaic on
-        TPU).  The kernel accumulates in float32, so pallas-scored argmaxes
-        can tie-flip vs the f64 XLA scorer on degenerate instances; the
-        XLA scorer is the bit-identical-to-numpy path.
+        (``repro.kernels.sic_rates``).  The kernel accumulates in float32,
+        so pallas-scored argmaxes can tie-flip vs the f64 XLA scorer on
+        degenerate instances; the XLA scorer is the bit-identical-to-numpy
+        path.
       * ``shards=N`` shards the V (candidate-subset) axis over the first N
-        local devices via ``shard_map`` (``repro.sharding.vertex``): every
+        local devices via ``shard_map`` (``repro.sharding.vertex``; asking
+        for more shards than there are local devices raises): every
         shard scores its slice of the enumeration and the global argmax is
         an in-mesh reduction — ``lax.pmax`` on the score, then ``lax.pmin``
         on the t-major global flat index among the maxima, then a ``psum``
@@ -57,7 +58,7 @@ path, in three layers:
         exactly.  ``shards=None`` skips ``shard_map`` entirely.
 
 Precision: the numpy engine is float64, so callers run this module under
-``jax.experimental.enable_x64()`` (the scheduling driver does) to keep the
+``jax.enable_x64(True)`` (the scheduling driver does) to keep the
 argmax tie-breaking bit-compatible with the host path.  Without x64 the same
 code runs in float32 — fine for kernels, not for schedule equivalence.
 """
@@ -344,7 +345,6 @@ def _fused_sharded(shards: int, pool: int, pmax: float, noise_power: float,
     ``shards`` local devices.  The whole while_loop runs inside shard_map:
     only the subset enumeration is sharded; gains/weights/solo and the
     carry are replicated (the in-mesh argmax reduction keeps them so)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import vertex as vertex_lib
@@ -359,11 +359,11 @@ def _fused_sharded(shards: int, pool: int, pmax: float, noise_power: float,
             axis_name=axis, n_shards=shards,
         )
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), P(), P(), P(axis, None)),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -388,10 +388,10 @@ def greedy_rounds_fused(
     host tail path resumes from when T*K > M.
 
     ``scorer`` picks the vertex scorer ("xla" | "pallas"); ``shards=N``
-    shards the V axis over min(N, local_device_count()) devices via
-    ``shard_map`` (see module docstring).  ``pool`` must already be clamped
-    to M by the caller (the scheduling driver does) so the position
-    enumeration matches the ranked pools.
+    shards the V axis over N local devices via ``shard_map`` (see module
+    docstring) and raises when fewer are present.  ``pool`` must already
+    be clamped to M by the caller (the scheduling driver does) so the
+    position enumeration matches the ranked pools.
     """
     if scorer not in SCORERS:
         raise ValueError(f"unknown scorer {scorer!r}; known: {SCORERS}")
@@ -400,9 +400,15 @@ def greedy_rounds_fused(
             gains_tm, weights_m, solo_tm, subs_pos_vk,
             pool=pool, pmax=pmax, noise_power=noise_power, scorer=scorer,
         )
+    from repro.core import errors
     from repro.sharding import vertex as vertex_lib
 
-    n = max(1, min(int(shards), vertex_lib.max_vertex_shards()))
+    n = int(shards)
+    available = vertex_lib.max_vertex_shards()
+    if n > available:
+        raise ValueError(errors.ERR_SHARDS_EXCEED_DEVICES.format(
+            option="shards", requested=n, available=available,
+        ))
     pad = vertex_lib.pad_rows_to_multiple(subs_pos_vk.shape[0], n)
     if pad:
         # Sentinel rows point at position ``pool``: past every ranked pool,

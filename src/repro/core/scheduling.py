@@ -555,7 +555,7 @@ def _greedy_rounds_jax_stepwise(
         gains_tm, weights_m, candidate_pool, k, pmax, noise_power
     )
     rounds = [()] * num_rounds
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jg = jnp.asarray(gains_tm, jnp.float64)
         jw = jnp.asarray(weights_m, jnp.float64)
         jsolo = jnp.asarray(solo_tm, jnp.float64)
@@ -606,7 +606,7 @@ def _greedy_rounds_jax_fused(
         gains_tm, weights_m, candidate_pool, k, pmax, noise_power
     )
     rounds = [()] * num_rounds
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         assign, done, avail = rates_jax.greedy_rounds_fused(
             jnp.asarray(gains_tm, jnp.float64),
             jnp.asarray(weights_m, jnp.float64),

@@ -30,8 +30,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.dorefa import BLOCK_ROWS, LANE
+from repro.kernels.platform import resolve_interpret
 
 TILE_ELEMS = BLOCK_ROWS * LANE
 
@@ -62,7 +64,7 @@ def _aggregate_block(flat, coeff, *, interpret):
         grid=grid,
         in_specs=[
             pl.BlockSpec((k, BLOCK_ROWS, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # (K,) coefficients
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
@@ -78,7 +80,7 @@ def weighted_aggregate_pallas(
     bits: int | None = None,
     *,
     levels: jax.Array | None = None,  # (K,) per-client a = 2^b - 1 (traced ok)
-    interpret: bool = True,
+    interpret: bool | None = None,
     chunk_elems: int | None = None,
 ) -> jax.Array:
     """sum_k w_k * scale_k * codes_k / a_k, shaped like ``codes[0]``.
@@ -97,6 +99,7 @@ def weighted_aggregate_pallas(
     """
     if (bits is None) == (levels is None):
         raise ValueError("pass exactly one of bits= or levels=")
+    interpret = resolve_interpret(interpret)
     k = codes.shape[0]
     out_shape = codes.shape[1:]
     n = 1
@@ -157,7 +160,7 @@ def _ota_block(flat, coeff, noise, *, interpret):
         grid=grid,
         in_specs=[
             pl.BlockSpec((k, BLOCK_ROWS, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # (K,) coefficients
             pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
@@ -172,7 +175,7 @@ def ota_aggregate_pallas(
     coeff: jax.Array,     # (K,) masked OTA weights w_k / sum_A(w) (traced ok)
     noise: jax.Array,     # flattened receiver noise, already 1/sqrt(eta)-scaled
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     chunk_elems: int | None = None,
 ) -> jax.Array:
     """sum_k coeff_k * deltas_k + noise, shaped like ``deltas[0]``.
@@ -190,6 +193,7 @@ def ota_aggregate_pallas(
     :func:`weighted_aggregate_pallas` with the noise strip chunked
     alongside, so only one brick is tile-padded at a time.
     """
+    interpret = resolve_interpret(interpret)
     k = deltas.shape[0]
     out_shape = deltas.shape[1:]
     n = 1

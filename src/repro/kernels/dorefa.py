@@ -6,8 +6,8 @@ width — and stream (BLOCK_ROWS, 128) tiles HBM->VMEM per grid step. The
 global max-abs scale is a cheap XLA reduction done by the ops.py wrapper
 (two-pass scheme); the kernels are single-pass elementwise given the scale.
 
-All kernels run under ``interpret=True`` on CPU for validation; on real TPU
-hardware the same ``pl.pallas_call`` lowers to Mosaic.
+Each kernel's ``interpret`` flag is resolved by :mod:`repro.kernels.platform`
+from the platform the call runs on.
 """
 from __future__ import annotations
 
@@ -16,6 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 LANE = 128          # TPU lane width: last-dim tile must be a multiple
 BLOCK_ROWS = 256    # (256, 128) fp32 tile = 128 KiB VMEM per operand
@@ -41,7 +44,8 @@ def _quantize_kernel(x_ref, scale_ref, o_ref, *, a: float):
 
 
 def quantize_codes_pallas(
-    x2d: jax.Array, scale: jax.Array, bits: int, *, interpret: bool = True
+    x2d: jax.Array, scale: jax.Array, bits: int,
+    *, interpret: bool | None = None,
 ) -> jax.Array:
     """x2d: (R, 128) float -> (R, 128) int32 codes. R % BLOCK_ROWS == 0."""
     rows = x2d.shape[0]
@@ -51,11 +55,11 @@ def quantize_codes_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # scalar scale, whole array
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (1,) scale
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2d, scale.reshape(1))
 
 
@@ -69,7 +73,8 @@ def _dequantize_kernel(c_ref, scale_ref, o_ref, *, a: float):
 
 
 def dequantize_codes_pallas(
-    codes2d: jax.Array, scale: jax.Array, bits: int, *, interpret: bool = True
+    codes2d: jax.Array, scale: jax.Array, bits: int,
+    *, interpret: bool | None = None,
 ) -> jax.Array:
     rows = codes2d.shape[0]
     grid = (rows // BLOCK_ROWS,)
@@ -78,11 +83,11 @@ def dequantize_codes_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (1,) scale
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes2d, scale.reshape(1))
 
 
@@ -99,7 +104,8 @@ def _qdq_kernel(x_ref, scale_ref, o_ref, *, a: float):
 
 
 def quantize_dequantize_pallas(
-    x2d: jax.Array, scale: jax.Array, bits: int, *, interpret: bool = True
+    x2d: jax.Array, scale: jax.Array, bits: int,
+    *, interpret: bool | None = None,
 ) -> jax.Array:
     rows = x2d.shape[0]
     grid = (rows // BLOCK_ROWS,)
@@ -108,9 +114,9 @@ def quantize_dequantize_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (1,) scale
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), x2d.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2d, scale.reshape(1))

@@ -8,9 +8,8 @@ sequential minor grid dimension — the canonical TPU flash-decode schedule.
 Invalid cache tail (positions >= valid_len) is masked, and fully-invalid
 blocks short-circuit via @pl.when (no MXU work issued).
 
-Validated in interpret mode against ref.flash_decode_ref
-(tests/test_kernels.py); on real TPU hardware the same pallas_call lowers
-to Mosaic.
+Validated against ref.flash_decode_ref (tests/test_kernels.py); the
+``interpret`` flag is resolved by :mod:`repro.kernels.platform`.
 """
 from __future__ import annotations
 
@@ -20,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 BLOCK_S = 256
 NEG_INF = -1e30
@@ -71,7 +72,7 @@ def flash_decode_pallas(
     valid_len: jax.Array,  # scalar int32: #valid cache positions
     *,
     block_s: int = BLOCK_S,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, hkv, g, d = q.shape
     s = k.shape[1]
@@ -82,7 +83,7 @@ def flash_decode_pallas(
         functools.partial(_kernel, block_s=block_s, n_blocks=n_blocks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),                      # valid_len
+            pl.BlockSpec(memory_space=pltpu.SMEM),                  # valid_len
             pl.BlockSpec((1, 1, g, d), lambda bi, hi, si: (bi, hi, 0, 0)),
             pl.BlockSpec((1, block_s, 1, d), lambda bi, hi, si: (bi, si, hi, 0)),
             pl.BlockSpec((1, block_s, 1, d), lambda bi, hi, si: (bi, si, hi, 0)),
@@ -94,5 +95,5 @@ def flash_decode_pallas(
             pltpu.VMEM((g, 128), jnp.float32),   # l (running denom)
             pltpu.VMEM((g, d), jnp.float32),     # acc
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(valid_len.reshape(1).astype(jnp.int32), q, k, v)

@@ -2,9 +2,8 @@
 
 Shapes are massaged here: flatten -> pad to (rows, 128) with rows a multiple
 of BLOCK_ROWS -> kernel -> unpad. ``use_pallas`` selects the Pallas path
-(interpret-mode on CPU, Mosaic on TPU); the default jnp path is used inside
-large jitted train steps where XLA fusion is already optimal and a
-Python-interpreted kernel would be pure overhead on this CPU container.
+(see :mod:`repro.kernels.platform` for where it runs); the default jnp path
+is used inside large jitted train steps where XLA fusion is already optimal.
 """
 from __future__ import annotations
 
@@ -106,8 +105,8 @@ def sic_weighted_rates(
 
     The scheduler-side (control-plane) engine is ``repro.core.rates``; this
     is the accelerator mirror for scoring huge candidate batches on device
-    (use_pallas selects the comparison-matrix Mosaic kernel, interpret mode
-    on CPU; the default path is the shared jnp engine in
+    (use_pallas selects the comparison-matrix Pallas kernel; the default
+    path is the shared jnp engine in
     ``repro.core.rates_jax``, which also powers the device-resident MWIS
     greedy in ``repro.core.scheduling`` at float64).
     """
@@ -124,7 +123,7 @@ def flash_decode(q, k, v, valid_len, *, use_pallas: bool = False,
     """One-token GQA decode attention over a cache (serving hot loop).
 
     q: (B, Hkv, G, D); k, v: (B, S, Hkv, D); valid_len: scalar int32.
-    use_pallas selects the Mosaic flash-decode kernel (interpret on CPU).
+    use_pallas selects the Pallas flash-decode kernel.
     """
     if use_pallas:
         return flash_decode_pallas(q, k, v, jnp.asarray(valid_len),

@@ -20,8 +20,8 @@ work — no gather, no sort network.
 Zero-padded sublane rows (rx = w = 0) are decoded last among ties by the
 j > i rule and carry zero weight, so padding never perturbs real rates.
 
-Runs under ``interpret=True`` on this CPU container; the same ``pallas_call``
-lowers to Mosaic on TPU.
+The ``interpret`` flag is resolved by :mod:`repro.kernels.platform` from the
+platform the call runs on.
 """
 from __future__ import annotations
 
@@ -30,6 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 LANE = 128          # TPU lane width
 BLOCK_V = 512       # candidate groups per grid step (4 lanes of 128)
@@ -59,7 +61,7 @@ def sic_weighted_rates_pallas(
     weights_vk: jax.Array,
     noise_power: float,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(V, K) powers/gains/weights -> (V,) weighted SIC sum rates."""
     v, k = powers_vk.shape
@@ -87,6 +89,6 @@ def sic_weighted_rates_pallas(
         ],
         out_specs=pl.BlockSpec((1, BLOCK_V), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, vp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rx, w)
     return out[0, :v]

@@ -27,7 +27,8 @@ def cell_mesh(shards: int):
 
     The multi-cell sweep shards whole independent simulations over it
     (``fl_engine.run_horizon_sharded``); like the scheduler's vertex mesh,
-    callers clamp ``shards`` to the local device count rather than failing.
+    ``fl.run_cell_sweep`` raises before asking for more devices than the
+    host has.
     """
     import numpy as np
     from jax.sharding import Mesh

@@ -15,7 +15,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.interpreters import batching
 
 from repro.models.params import ParamSpec
 
@@ -294,40 +293,13 @@ def embedding_schema(vocab: int, d: int, *, tie: bool):
     return sch
 
 
-if jax.lax.optimization_barrier_p not in batching.primitive_batchers:
-    # ... nor a batching rule: the barrier is identity-semantics (it only
-    # pins XLA scheduling), so batching is bind-on-the-batched-operands
-    # with the batch dims passed through unchanged.  Without this, any
-    # vmap over a model forward (the FL engine's client axis) fails.
-    def _optimization_barrier_batcher(args, dims, **params):
-        outs = jax.lax.optimization_barrier_p.bind(*args, **params)
-        return outs, dims
-
-    batching.primitive_batchers[jax.lax.optimization_barrier_p] = (
-        _optimization_barrier_batcher
-    )
-
-
-@jax.custom_jvp
-def _grad_safe_barrier(x):
-    # optimization_barrier has no differentiation rule on this JAX version;
-    # the barrier only pins XLA scheduling on the primal, so the tangent
-    # passes straight through (identity JVP, transposable for reverse mode).
-    return jax.lax.optimization_barrier(x)
-
-
-@_grad_safe_barrier.defjvp
-def _grad_safe_barrier_jvp(primals, tangents):
-    (x,), (dx,) = primals, tangents
-    return jax.lax.optimization_barrier(x), dx
-
-
 def embed(p, tokens):
     # optimization_barrier pins the table convert BEFORE the gather: without
     # it XLA converts after the gather and the vocab-shard partial-sum
     # all-reduce of the (B, S, D) activations runs in fp32 (2x bytes;
-    # EXPERIMENTS.md §Perf pair B).
-    table = _grad_safe_barrier(p["tokens"].astype(COMPUTE_DTYPE))
+    # EXPERIMENTS.md §Perf pair B).  JAX batches and differentiates the
+    # barrier itself (the FL engine vmaps and grads through this forward).
+    table = jax.lax.optimization_barrier(p["tokens"].astype(COMPUTE_DTYPE))
     return constrain(table[tokens], "residual")
 
 

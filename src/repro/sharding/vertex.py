@@ -30,9 +30,9 @@ def max_vertex_shards() -> int:
 def vertex_mesh(shards: int) -> Mesh:
     """1-D mesh of the first ``shards`` local devices, axis ``"v"``.
 
-    ``shards`` must be in [1, local_device_count()]; callers clamp (the
-    scheduler degrades to fewer shards rather than failing when a config
-    asks for more devices than the host has).
+    ``shards`` must be in [1, local_device_count()]; the scheduler raises
+    ``errors.ERR_SHARDS_EXCEED_DEVICES`` before asking for more devices
+    than the host has.
     """
     if not 1 <= shards <= jax.local_device_count():
         raise ValueError(

@@ -189,6 +189,21 @@ def test_cell_sweep_sharded_matches_single_mesh(tiny_world):
                                           sharded[c][s].accuracies())
 
 
+def test_cell_sweep_rejects_more_shards_than_devices(tiny_world):
+    """Asking for more cell shards than local devices raises with both
+    counts; it never runs on fewer devices in silence."""
+    ds, cell, shards = tiny_world
+    cfg = _cfg(m=4, group_size=2, rounds=2, horizon="scan")
+    n = jax.local_device_count() + 1
+    with pytest.raises(
+        ValueError,
+        match=(f"cell_shards={n} asks for {n} devices, but only "
+               f"{n - 1} local devices are present"),
+    ):
+        fl.run_cell_sweep(ds, shards, cell, cfg, num_cells=2,
+                          seeds_per_cell=1, cell_shards=n)
+
+
 def _register_untraced_online():
     """A registered online policy WITHOUT the traced protocol — the
     rejection case since the built-in online policies all gained

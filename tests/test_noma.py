@@ -39,11 +39,14 @@ def test_sic_sum_rate_identity_property(k, seed):
     g = jnp.asarray(_gains(k, seed))
     p = jnp.asarray(np.random.default_rng(seed + 1).uniform(1e-4, 0.01, k))
     rates = noma.rates(p, g, NOISE)
-    total_rx = jnp.sum(p * g**2)
+    # Reference in f64.  The f32 rates carry an absolute error of a few
+    # 1e-7 bits, which at a weak group's ~4e-4-bit sum rate exceeds rtol
+    # alone, so the bound is rtol plus an absolute 1e-6 bits.
+    p64, g64 = np.asarray(p, np.float64), np.asarray(g, np.float64)
     np.testing.assert_allclose(
         float(jnp.sum(rates)),
-        float(jnp.log2(1 + total_rx / NOISE)),
-        rtol=1e-4,
+        np.log2(1 + np.sum(p64 * g64**2) / NOISE),
+        rtol=1e-4, atol=1e-6,
     )
 
 

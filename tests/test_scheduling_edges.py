@@ -196,15 +196,21 @@ def test_unknown_scorer_raises():
 def test_sharded_fused_loop_bit_identical(shards):
     """shard_map over the vertex axis (in-mesh argmax reduction) must not
     change the schedule.  shards=1 exercises the collective code path on a
-    single-device mesh; shards above the local device count clamp (this
-    container has one CPU device — multi-shard equality is additionally
-    pinned by the forced-host-device run in CI-less environments via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count``)."""
-    pytest.importorskip("jax")
+    single-device mesh.  Multi-shard equality needs that many devices
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=4``); with fewer,
+    the request must raise rather than run on fewer shards in silence."""
+    jax = pytest.importorskip("jax")
     gains, w = _instance(24, 4, seed=12)
     a = scheduling.lazy_greedy_schedule(
         gains, w, 3, noise_power=NOISE, candidate_pool=10
     )
+    if shards > jax.local_device_count():
+        with pytest.raises(ValueError, match="local devices are present"):
+            scheduling.lazy_greedy_schedule(
+                gains, w, 3, noise_power=NOISE, candidate_pool=10,
+                backend="jax", shards=shards,
+            )
+        return
     b = scheduling.lazy_greedy_schedule(
         gains, w, 3, noise_power=NOISE, candidate_pool=10, backend="jax",
         shards=shards,
@@ -231,7 +237,7 @@ def test_greedy_step_clamps_candidate_pool_beyond_m():
     m, t, pool = 6, 3, 16
     gains, w = _instance(m, t, seed=0)
     solo = w * np.log2(1.0 + (0.01 * gains**2) / NOISE)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jg = jnp.asarray(gains, jnp.float64)
         jw = jnp.asarray(w, jnp.float64)
         jsolo = jnp.asarray(solo, jnp.float64)

@@ -55,7 +55,8 @@ def test_noma_rounds_faster_than_tdma(small_world):
 
 def test_adaptive_bits_recorded_and_bounded(small_world):
     ds, cell, shards = small_world
-    res = _run(ds, cell, shards, rounds=5)
+    # not 5 or 9: tests/test_sanitizers.py counts compiles at those sizes
+    res = _run(ds, cell, shards, rounds=4)
     for log in res.logs:
         assert np.all(log.bits >= 1) and np.all(log.bits <= 32)
         assert np.all(log.compression_ratios >= 1.0)
@@ -193,7 +194,7 @@ def test_scheduler_weighted_rate_ordering(small_world):
     key = jax.random.PRNGKey(0)
     dist = channel.sample_positions(key, cell)
     gains = np.asarray(channel.sample_round_channels(
-        jax.random.fold_in(key, 2), dist, cell, 5))
+        jax.random.fold_in(key, 2), dist, cell, 4))   # not 5: see above
     g = scheduling.lazy_greedy_schedule(
         gains, weights, 3, pmax=cell.max_power_w,
         noise_power=cell.noise_power_w)

@@ -96,7 +96,7 @@ _TRACING_TRANSFORMS = {
     "jax.checkpoint", "jax.remat",
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.map", "jax.lax.switch",
-    "jax.experimental.shard_map.shard_map",
+    "jax.experimental.shard_map.shard_map", "jax.shard_map",
 }
 
 _JIT_WRAPPERS = {"jax.jit", "jax.vmap", "jax.pmap"}          # FLC001

@@ -105,9 +105,15 @@ def nan_guard(enable: bool = True):
     de-optimizes (per-primitive checks + possible retraces), so it is a
     debugging mode, never a default.  The previous setting is restored
     even if the block raises.
+
+    Entering the guard clears JAX's compilation caches: on JAX 0.9.0 a
+    program already compiled and dispatched through the fast path keeps
+    running unchecked after the flag flips, so a NaN from it would pass.
     """
     prev = jax.config.jax_debug_nans
     jax.config.update("jax_debug_nans", bool(enable))
+    if enable:
+        jax.clear_caches()
     try:
         yield
     finally:
