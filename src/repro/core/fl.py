@@ -68,6 +68,7 @@ from repro.core import power as power_lib
 from repro.core import quantization as qlib
 from repro.data.client_bank import ClientBank, EvalBank, eval_sample_plan
 from repro.models.fl_models import get_fl_model
+from repro.utils import spans
 from repro.utils.tree import tree_count
 
 
@@ -479,6 +480,18 @@ def run_federated_learning(
 # Scanned horizons: the whole precomputed simulation as ONE device program
 # --------------------------------------------------------------------------
 
+def _spanned(name: str):
+    """Run the decorated host function inside the span ``name``
+    (``repro.utils.spans``; README "Tracing a horizon" lists them)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with spans.span(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
+
+
 @dataclasses.dataclass
 class _HorizonPlan:
     """Host-precomputed plan for one simulation instance (one seed).
@@ -505,6 +518,7 @@ class _HorizonPlan:
     eval_idx: "np.ndarray | None"  # (T, n) eval sample plan; None = full set
 
 
+@_spanned("fl.plan")
 def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
     """Host precompute for one scanned instance.
 
@@ -538,7 +552,8 @@ def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
             raise ValueError(
                 errors.ERR_SCAN_ONLINE_POLICY.format(scheduler=cfg.scheduler)
             )
-        schedule = make_schedule(gains, weights, cell, cfg, policy=policy)
+        with spans.span("fl.schedule"):
+            schedule = make_schedule(gains, weights, cell, cfg, policy=policy)
     else:
         schedule.validate(cell.num_devices, cfg.group_size)
 
@@ -603,6 +618,16 @@ def _horizon_statics(
         ota_threshold=float(cfg.ota_threshold) if ota else 0.0,
         pmax=float(cell.max_power_w) if ota else 0.0,
     )
+
+
+def _build_banks(dataset, shards, cfg: FLConfig):
+    """The device-resident client and test banks a scanned horizon
+    program reads: host padding plus the upload, anew every call."""
+    with spans.span("fl.bank"):
+        bank = ClientBank.build(
+            dataset.x_train, dataset.y_train, shards, cfg.batch_size
+        )
+        return bank, EvalBank.build(dataset.x_test, dataset.y_test)
 
 
 def _eval_mask(num_rounds: int, eval_every: int) -> np.ndarray:
@@ -694,6 +719,7 @@ def _assemble_horizon_result(
     return FLResult(logs, final_params, scheme)
 
 
+@_spanned("fl.horizon")
 def run_horizon_scanned(
     dataset,
     shards: list,
@@ -742,10 +768,7 @@ def run_horizon_scanned(
             eval_every=eval_every, progress=progress,
         )
     plan = _horizon_setup(dataset, shards, cell, cfg, uplink, schedule)
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
@@ -753,23 +776,27 @@ def run_horizon_scanned(
     eidx = (np.zeros((T, 1), np.int32) if eval_full else plan.eval_idx)
     nb = max(bank.n_batches_for(g) for g in plan.schedule.rounds)
 
-    final, bits_tk, kept_tk, accs_t = fl_engine.run_horizon(
-        plan.params0,
-        jnp.asarray(plan.dev_tk),
-        jnp.asarray(plan.budgets_tk),
-        jnp.asarray(plan.aggw_tk, jnp.float32),
-        jnp.asarray(plan.gains_tk),
-        jnp.asarray(plan.noise_keys),
-        jnp.asarray(eval_mask),
-        jnp.asarray(eidx),
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        nb=int(nb),
-        **_horizon_statics(cfg, plan.payload, eval_full, cell, uplink),
-    )
-    return _assemble_horizon_result(
-        plan, cfg, uplink, eval_mask, np.asarray(bits_tk), np.asarray(accs_t),
-        final, progress, kept_tk=np.asarray(kept_tk),
-    )
+    with spans.span("fl.dispatch"):
+        final, bits_tk, kept_tk, accs_t = fl_engine.run_horizon(
+            plan.params0,
+            jnp.asarray(plan.dev_tk),
+            jnp.asarray(plan.budgets_tk),
+            jnp.asarray(plan.aggw_tk, jnp.float32),
+            jnp.asarray(plan.gains_tk),
+            jnp.asarray(plan.noise_keys),
+            jnp.asarray(eval_mask),
+            jnp.asarray(eidx),
+            bank.xb, bank.yb, ebank.xe, ebank.ye,
+            nb=int(nb),
+            **_horizon_statics(cfg, plan.payload, eval_full, cell, uplink),
+        )
+    with spans.span("fl.sync"):
+        bits_tk, kept_tk, accs_t = jax.device_get((bits_tk, kept_tk, accs_t))
+    with spans.span("fl.replay"):
+        return _assemble_horizon_result(
+            plan, cfg, uplink, eval_mask, bits_tk, accs_t, final, progress,
+            kept_tk=kept_tk,
+        )
 
 
 # --------------------------------------------------------------------------
@@ -821,6 +848,7 @@ def _online_statics(cfg: FLConfig, cell, uplink, policy) -> dict:
     )
 
 
+@_spanned("fl.plan")
 def _online_horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink):
     """Host precompute for one online scanned instance.
 
@@ -843,8 +871,9 @@ def _online_horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink):
                                    cfg.num_rounds)
     )
 
-    policy = scheduling.get_policy(cfg.scheduler)
-    aux = policy.init_traced(gains, weights, policy_config(cell, cfg))
+    with spans.span("fl.schedule"):
+        policy = scheduling.get_policy(cfg.scheduler)
+        aux = policy.init_traced(gains, weights, policy_config(cell, cfg))
 
     dl_gains = chan.large_scale_gain(dist, cell)
     dl_time = float(chan.downlink_time_seconds(payload, dl_gains, cell))
@@ -937,10 +966,7 @@ def _run_horizon_online(
     after which :func:`_finalize_online_plan` rebuilds the f64 logs.
     """
     plan = _online_horizon_setup(dataset, shards, cell, cfg, uplink)
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
@@ -952,27 +978,30 @@ def _run_horizon_online(
     nb = bank.n_batches_for(range(cell.num_devices))
     policy = scheduling.get_policy(cfg.scheduler)
 
-    out = fl_engine.run_horizon_online(
-        plan.params0,
-        jnp.asarray(plan.solo),
-        jnp.asarray(plan.gains, jnp.float32),
-        jnp.asarray(plan.weights, jnp.float32),
-        jnp.asarray(plan.sizes, jnp.float32),
-        jnp.asarray(plan.noise_keys),
-        jnp.asarray(eval_mask), jnp.asarray(eidx),
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        nb=int(nb),
-        **_online_statics(cfg, cell, uplink, policy),
-        **_horizon_statics(cfg, plan.payload, eval_full, cell, uplink),
-    )
+    with spans.span("fl.dispatch"):
+        out = fl_engine.run_horizon_online(
+            plan.params0,
+            jnp.asarray(plan.solo),
+            jnp.asarray(plan.gains, jnp.float32),
+            jnp.asarray(plan.weights, jnp.float32),
+            jnp.asarray(plan.sizes, jnp.float32),
+            jnp.asarray(plan.noise_keys),
+            jnp.asarray(eval_mask), jnp.asarray(eidx),
+            bank.xb, bank.yb, ebank.xe, ebank.ye,
+            nb=int(nb),
+            **_online_statics(cfg, cell, uplink, policy),
+            **_horizon_statics(cfg, plan.payload, eval_full, cell, uplink),
+        )
     # ONE host sync for the whole horizon: schedule, bits, accuracies and
     # the final model come back together
-    final, dev_tk, mask_tk, bits_tk, kept_tk, accs_t = jax.device_get(out)
-    hplan = _finalize_online_plan(plan, cfg, cell, uplink, dev_tk, mask_tk)
-    return _assemble_horizon_result(
-        hplan, cfg, uplink, eval_mask, bits_tk, accs_t, final, progress,
-        kept_tk=kept_tk,
-    )
+    with spans.span("fl.sync"):
+        final, dev_tk, mask_tk, bits_tk, kept_tk, accs_t = jax.device_get(out)
+    with spans.span("fl.replay"):
+        hplan = _finalize_online_plan(plan, cfg, cell, uplink, dev_tk, mask_tk)
+        return _assemble_horizon_result(
+            hplan, cfg, uplink, eval_mask, bits_tk, accs_t, final, progress,
+            kept_tk=kept_tk,
+        )
 
 
 def _stack_online_plans(plans):
@@ -1005,40 +1034,45 @@ def _run_horizon_vmapped_online(
         )
         for s in seeds
     ]
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
-    params_s, solo, gains, keys, eidx, eval_full = _stack_online_plans(plans)
     nb = bank.n_batches_for(range(cell.num_devices))
     policy = scheduling.get_policy(cfg.scheduler)
 
-    out = fl_engine.run_horizon_online_vmapped(
-        params_s,
-        jnp.asarray(solo), jnp.asarray(gains),
-        jnp.asarray(plans[0].weights, jnp.float32),
-        jnp.asarray(plans[0].sizes, jnp.float32),
-        jnp.asarray(keys), jnp.asarray(eval_mask), jnp.asarray(eidx),
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        nb=int(nb),
-        **_online_statics(cfg, cell, uplink, policy),
-        **_horizon_statics(cfg, plans[0].payload, eval_full, cell, uplink),
-    )
-    final_s, dev_s, mask_s, bits_s, kept_s, accs_s = jax.device_get(out)
-    results = []
-    for s, plan in enumerate(plans):
-        scfg = dataclasses.replace(cfg, seed=int(seeds[s]))
-        hplan = _finalize_online_plan(
-            plan, scfg, cell, uplink, dev_s[s], mask_s[s]
+    with spans.span("fl.dispatch"):
+        params_s, solo, gains, keys, eidx, eval_full = _stack_online_plans(
+            plans
         )
-        fp = jax.tree_util.tree_map(lambda l, s=s: jnp.asarray(l[s]), final_s)
-        results.append(_assemble_horizon_result(
-            hplan, scfg, uplink, eval_mask, bits_s[s], accs_s[s], fp,
-            kept_tk=kept_s[s],
-        ))
+        out = fl_engine.run_horizon_online_vmapped(
+            params_s,
+            jnp.asarray(solo), jnp.asarray(gains),
+            jnp.asarray(plans[0].weights, jnp.float32),
+            jnp.asarray(plans[0].sizes, jnp.float32),
+            jnp.asarray(keys), jnp.asarray(eval_mask), jnp.asarray(eidx),
+            bank.xb, bank.yb, ebank.xe, ebank.ye,
+            nb=int(nb),
+            **_online_statics(cfg, cell, uplink, policy),
+            **_horizon_statics(cfg, plans[0].payload, eval_full, cell,
+                               uplink),
+        )
+    with spans.span("fl.sync"):
+        final_s, dev_s, mask_s, bits_s, kept_s, accs_s = jax.device_get(out)
+    results = []
+    with spans.span("fl.replay"):
+        for s, plan in enumerate(plans):
+            scfg = dataclasses.replace(cfg, seed=int(seeds[s]))
+            hplan = _finalize_online_plan(
+                plan, scfg, cell, uplink, dev_s[s], mask_s[s]
+            )
+            fp = jax.tree_util.tree_map(
+                lambda l, s=s: jnp.asarray(l[s]), final_s
+            )
+            results.append(_assemble_horizon_result(
+                hplan, scfg, uplink, eval_mask, bits_s[s], accs_s[s], fp,
+                kept_tk=kept_s[s],
+            ))
     return results
 
 
@@ -1061,14 +1095,14 @@ def _run_cell_sweep_online(
         for c in range(C)
         for s in range(S)
     ]
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
-    params_f, solo, gains, keys, eidx, eval_full = _stack_online_plans(flat)
+    with spans.span("fl.dispatch"):
+        params_f, solo, gains, keys, eidx, eval_full = _stack_online_plans(
+            flat
+        )
     nb = bank.n_batches_for(range(cell.num_devices))
     policy = scheduling.get_policy(cfg.scheduler)
     weights_j = jnp.asarray(flat[0].weights, jnp.float32)
@@ -1080,14 +1114,15 @@ def _run_cell_sweep_online(
 
     def finish(i, c, s, final_np, dev_i, mask_i, bits_i, kept_i, accs_i):
         scfg = dataclasses.replace(cfg, seed=inst_seeds[c][s])
-        hplan = _finalize_online_plan(
-            flat[i], scfg, cell, uplink, dev_i, mask_i
-        )
-        fp = jax.tree_util.tree_map(jnp.asarray, final_np)
-        return _assemble_horizon_result(
-            hplan, scfg, uplink, eval_mask, bits_i, accs_i, fp,
-            kept_tk=kept_i,
-        )
+        with spans.span("fl.replay"):
+            hplan = _finalize_online_plan(
+                flat[i], scfg, cell, uplink, dev_i, mask_i
+            )
+            fp = jax.tree_util.tree_map(jnp.asarray, final_np)
+            return _assemble_horizon_result(
+                hplan, scfg, uplink, eval_mask, bits_i, accs_i, fp,
+                kept_tk=kept_i,
+            )
 
     if shards_n == 1:
         emask_j = jnp.asarray(eval_mask)
@@ -1096,17 +1131,19 @@ def _run_cell_sweep_online(
             row = []
             for s in range(S):
                 i = c * S + s
-                out = fl_engine.run_horizon_online(
-                    flat[i].params0,
-                    jnp.asarray(solo[i]), jnp.asarray(gains[i]),
-                    weights_j, sizes_j,
-                    jnp.asarray(keys[i]), emask_j, jnp.asarray(eidx[i]),
-                    bank.xb, bank.yb, ebank.xe, ebank.ye,
-                    nb=int(nb), **statics,
-                )
-                final, dev_i, mask_i, bits_i, kept_i, accs_i = (
-                    jax.device_get(out)
-                )
+                with spans.span("fl.dispatch"):
+                    out = fl_engine.run_horizon_online(
+                        flat[i].params0,
+                        jnp.asarray(solo[i]), jnp.asarray(gains[i]),
+                        weights_j, sizes_j,
+                        jnp.asarray(keys[i]), emask_j, jnp.asarray(eidx[i]),
+                        bank.xb, bank.yb, ebank.xe, ebank.ye,
+                        nb=int(nb), **statics,
+                    )
+                with spans.span("fl.sync"):
+                    final, dev_i, mask_i, bits_i, kept_i, accs_i = (
+                        jax.device_get(out)
+                    )
                 row.append(finish(
                     i, c, s, final, dev_i, mask_i, bits_i, kept_i, accs_i
                 ))
@@ -1131,15 +1168,19 @@ def _run_cell_sweep_online(
             lambda l: jnp.concatenate([l, l[:pad]]), params_cs
         )
 
-    out = fl_engine.run_horizon_online_sharded(
-        params_cs,
-        jnp.asarray(solo_cs), jnp.asarray(gains_cs), jnp.asarray(keys_cs),
-        jnp.asarray(eval_mask), jnp.asarray(eidx_cs),
-        weights_j, sizes_j,
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        shards=shards_n, nb=int(nb), **statics,
-    )
-    final_cs, dev_cs, mask_cs, bits_cs, kept_cs, accs_cs = jax.device_get(out)
+    with spans.span("fl.dispatch"):
+        out = fl_engine.run_horizon_online_sharded(
+            params_cs,
+            jnp.asarray(solo_cs), jnp.asarray(gains_cs),
+            jnp.asarray(keys_cs), jnp.asarray(eval_mask),
+            jnp.asarray(eidx_cs), weights_j, sizes_j,
+            bank.xb, bank.yb, ebank.xe, ebank.ye,
+            shards=shards_n, nb=int(nb), **statics,
+        )
+    with spans.span("fl.sync"):
+        final_cs, dev_cs, mask_cs, bits_cs, kept_cs, accs_cs = (
+            jax.device_get(out)
+        )
     results = []
     for c in range(C):
         row = []
@@ -1155,6 +1196,7 @@ def _run_cell_sweep_online(
     return results
 
 
+@_spanned("fl.horizon")
 def run_horizon_vmapped(
     dataset,
     shards: list,
@@ -1197,42 +1239,45 @@ def run_horizon_vmapped(
         )
         for s in seeds
     ]
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
-    params_s, dev, bud, agg, gains, keys, eidx, eval_full, nb = _stack_plans(
-        plans, bank, T
-    )
-
-    final_s, bits_stk, kept_stk, accs_st = fl_engine.run_horizon_vmapped(
-        params_s,
-        jnp.asarray(dev), jnp.asarray(bud), jnp.asarray(agg, jnp.float32),
-        jnp.asarray(gains), jnp.asarray(keys),
-        jnp.asarray(eval_mask), jnp.asarray(eidx),
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        nb=int(nb),
-        **_horizon_statics(cfg, plans[0].payload, eval_full, cell, uplink),
-    )
-    bits_np, accs_np = np.asarray(bits_stk), np.asarray(accs_st)
-    kept_np = np.asarray(kept_stk)
+    with spans.span("fl.dispatch"):
+        params_s, dev, bud, agg, gains, keys, eidx, eval_full, nb = (
+            _stack_plans(plans, bank, T)
+        )
+        final_s, bits_stk, kept_stk, accs_st = fl_engine.run_horizon_vmapped(
+            params_s,
+            jnp.asarray(dev), jnp.asarray(bud), jnp.asarray(agg, jnp.float32),
+            jnp.asarray(gains), jnp.asarray(keys),
+            jnp.asarray(eval_mask), jnp.asarray(eidx),
+            bank.xb, bank.yb, ebank.xe, ebank.ye,
+            nb=int(nb),
+            **_horizon_statics(cfg, plans[0].payload, eval_full, cell,
+                               uplink),
+        )
     # unstack on the host for the same reason _stack_plans stacks there:
     # a traced l[s] compiles one dynamic_slice program per leaf shape per
     # sweep width, making the program count depend on the seed count
-    final_np = jax.tree_util.tree_map(np.asarray, final_s)
+    with spans.span("fl.sync"):
+        bits_np, kept_np, accs_np, final_np = jax.device_get(
+            (bits_stk, kept_stk, accs_st, final_s)
+        )
     results = []
-    for s, plan in enumerate(plans):
-        fp = jax.tree_util.tree_map(lambda l, s=s: jnp.asarray(l[s]), final_np)
-        results.append(_assemble_horizon_result(
-            plan, dataclasses.replace(cfg, seed=seeds[s]), uplink, eval_mask,
-            bits_np[s], accs_np[s], fp, kept_tk=kept_np[s],
-        ))
+    with spans.span("fl.replay"):
+        for s, plan in enumerate(plans):
+            fp = jax.tree_util.tree_map(
+                lambda l, s=s: jnp.asarray(l[s]), final_np
+            )
+            results.append(_assemble_horizon_result(
+                plan, dataclasses.replace(cfg, seed=seeds[s]), uplink,
+                eval_mask, bits_np[s], accs_np[s], fp, kept_tk=kept_np[s],
+            ))
     return results
 
 
+@_spanned("fl.horizon")
 def run_cell_sweep(
     dataset,
     shards: list,
@@ -1305,17 +1350,15 @@ def run_cell_sweep(
         ]
         for c in range(C)
     ]
-    bank = ClientBank.build(
-        dataset.x_train, dataset.y_train, shards, cfg.batch_size
-    )
-    ebank = EvalBank.build(dataset.x_test, dataset.y_test)
+    bank, ebank = _build_banks(dataset, shards, cfg)
 
     T = cfg.num_rounds
     eval_mask = _eval_mask(T, eval_every)
     flat = [p for row in plans for p in row]
-    params_f, dev, bud, agg, gains, keys, eidx, eval_full, nb = _stack_plans(
-        flat, bank, T
-    )
+    with spans.span("fl.dispatch"):
+        params_f, dev, bud, agg, gains, keys, eidx, eval_full, nb = (
+            _stack_plans(flat, bank, T)
+        )
     statics = _horizon_statics(cfg, flat[0].payload, eval_full, cell, uplink)
 
     if shards_n == 1:
@@ -1328,20 +1371,27 @@ def run_cell_sweep(
             row = []
             for s in range(S):
                 i = c * S + s
-                final, bits_tk, kept_tk, accs_t = fl_engine.run_horizon(
-                    flat[i].params0,
-                    jnp.asarray(dev[i]), jnp.asarray(bud[i]),
-                    jnp.asarray(agg[i], jnp.float32),
-                    jnp.asarray(gains[i]), jnp.asarray(keys[i]),
-                    emask_j, jnp.asarray(eidx[i]),
-                    bank.xb, bank.yb, ebank.xe, ebank.ye,
-                    nb=int(nb), **statics,
-                )
-                row.append(_assemble_horizon_result(
-                    flat[i], dataclasses.replace(cfg, seed=inst_seeds[c][s]),
-                    uplink, eval_mask, np.asarray(bits_tk),
-                    np.asarray(accs_t), final, kept_tk=np.asarray(kept_tk),
-                ))
+                with spans.span("fl.dispatch"):
+                    final, bits_tk, kept_tk, accs_t = fl_engine.run_horizon(
+                        flat[i].params0,
+                        jnp.asarray(dev[i]), jnp.asarray(bud[i]),
+                        jnp.asarray(agg[i], jnp.float32),
+                        jnp.asarray(gains[i]), jnp.asarray(keys[i]),
+                        emask_j, jnp.asarray(eidx[i]),
+                        bank.xb, bank.yb, ebank.xe, ebank.ye,
+                        nb=int(nb), **statics,
+                    )
+                with spans.span("fl.sync"):
+                    bits_tk, kept_tk, accs_t = jax.device_get(
+                        (bits_tk, kept_tk, accs_t)
+                    )
+                with spans.span("fl.replay"):
+                    row.append(_assemble_horizon_result(
+                        flat[i],
+                        dataclasses.replace(cfg, seed=inst_seeds[c][s]),
+                        uplink, eval_mask, bits_tk, accs_t, final,
+                        kept_tk=kept_tk,
+                    ))
             results.append(row)
         return results
 
@@ -1368,29 +1418,35 @@ def run_cell_sweep(
             lambda l: jnp.concatenate([l, l[:pad]]), params_cs
         )
 
-    final_cs, bits_cstk, kept_cstk, accs_cst = fl_engine.run_horizon_sharded(
-        params_cs,
-        jnp.asarray(dev), jnp.asarray(bud), jnp.asarray(agg, jnp.float32),
-        jnp.asarray(gains), jnp.asarray(keys),
-        jnp.asarray(eval_mask), jnp.asarray(eidx),
-        bank.xb, bank.yb, ebank.xe, ebank.ye,
-        shards=shards_n, nb=int(nb), **statics,
-    )
-    bits_np = np.asarray(bits_cstk)[:C]
-    kept_np = np.asarray(kept_cstk)[:C]
-    accs_np = np.asarray(accs_cst)[:C]
-    results = []
-    for c in range(C):
-        row = []
-        for s in range(S):
-            fp = jax.tree_util.tree_map(
-                lambda l, c=c, s=s: l[c, s], final_cs
+    with spans.span("fl.dispatch"):
+        final_cs, bits_cstk, kept_cstk, accs_cst = (
+            fl_engine.run_horizon_sharded(
+                params_cs,
+                jnp.asarray(dev), jnp.asarray(bud),
+                jnp.asarray(agg, jnp.float32),
+                jnp.asarray(gains), jnp.asarray(keys),
+                jnp.asarray(eval_mask), jnp.asarray(eidx),
+                bank.xb, bank.yb, ebank.xe, ebank.ye,
+                shards=shards_n, nb=int(nb), **statics,
             )
-            row.append(_assemble_horizon_result(
-                plans[c][s],
-                dataclasses.replace(cfg, seed=inst_seeds[c][s]), uplink,
-                eval_mask, bits_np[c, s], accs_np[c, s], fp,
-                kept_tk=kept_np[c, s],
-            ))
-        results.append(row)
+        )
+    with spans.span("fl.sync"):
+        bits_np, kept_np, accs_np = jax.device_get(
+            (bits_cstk, kept_cstk, accs_cst)
+        )
+    results = []
+    with spans.span("fl.replay"):
+        for c in range(C):
+            row = []
+            for s in range(S):
+                fp = jax.tree_util.tree_map(
+                    lambda l, c=c, s=s: l[c, s], final_cs
+                )
+                row.append(_assemble_horizon_result(
+                    plans[c][s],
+                    dataclasses.replace(cfg, seed=inst_seeds[c][s]), uplink,
+                    eval_mask, bits_np[c, s], accs_np[c, s], fp,
+                    kept_tk=kept_np[c, s],
+                ))
+            results.append(row)
     return results

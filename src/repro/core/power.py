@@ -32,6 +32,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import rates as rates_lib
+from repro.utils import spans
 
 
 @dataclasses.dataclass
@@ -340,10 +341,10 @@ def mapel_batched(
     weights = np.asarray(weights_gk, dtype=np.float64)
     g_cnt, k_cnt = gains.shape
     if g_cnt == 0 or k_cnt == 0:
-        return BatchedPowerSolution(
+        return _counted(BatchedPowerSolution(
             np.zeros((g_cnt, k_cnt)), np.zeros(g_cnt),
             np.zeros(g_cnt, dtype=int), np.zeros(g_cnt),
-        )
+        ), eps, max_iter)
     order = np.argsort(-gains, axis=1, kind="stable")   # strongest first
     g = np.take_along_axis(gains, order, axis=1)
     w = np.take_along_axis(weights, order, axis=1)
@@ -354,9 +355,9 @@ def mapel_batched(
         rate = w[:, 0] * np.log2(z)
         powers = np.zeros((g_cnt, 1))
         np.put_along_axis(powers, order, p_sorted, axis=1)
-        return BatchedPowerSolution(
+        return _counted(BatchedPowerSolution(
             powers, rate, np.zeros(g_cnt, dtype=int), np.zeros(g_cnt)
-        )
+        ), eps, max_iter)
 
     z_top = 1.0 + pmax * g**2 / noise_power
     verts = [[z_top[i]] for i in range(g_cnt)]
@@ -419,7 +420,22 @@ def mapel_batched(
     powers = np.zeros((g_cnt, k_cnt))
     np.put_along_axis(powers, order, p_fin, axis=1)
     rate = rates_lib.batched_weighted_rates(powers, gains, weights, noise_power)
-    return BatchedPowerSolution(powers, rate, it, np.maximum(gap, 0.0))
+    return _counted(
+        BatchedPowerSolution(powers, rate, it, np.maximum(gap, 0.0)),
+        eps, max_iter,
+    )
+
+
+def _counted(sol: BatchedPowerSolution, eps, max_iter):
+    """Add one solve to the ``power.*`` counters (``repro.utils.spans``):
+    groups, polyblock iterations, and groups stopped at ``max_iter`` short
+    of ``eps``."""
+    spans.count("power.mapel_groups", len(sol.iterations))
+    spans.count("power.mapel_iters", int(np.sum(sol.iterations)))
+    spans.count("power.mapel_unconverged", int(np.sum(
+        (sol.iterations >= max_iter) & (sol.gaps > eps)
+    )))
+    return sol
 
 
 # --------------------------------------------------------------------------

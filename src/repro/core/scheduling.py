@@ -128,6 +128,7 @@ import numpy as np
 
 from repro.core import power as power_lib
 from repro.core import rates as rates_lib
+from repro.utils import spans
 
 PowerFn = Callable[[np.ndarray, np.ndarray], np.ndarray]
 # (gains_K, weights_K) -> powers_K; may carry a ``batched`` attribute
@@ -320,7 +321,8 @@ def finalize_schedule(rounds, gains_tm, weights_m, power_fn, noise_power, method
         if kk == 0:
             p = np.zeros((len(ts), 0))
         else:
-            p = _batched_powers(power_fn, g, w)
+            with spans.span("fl.power"):
+                p = _batched_powers(power_fn, g, w)
         r = rates_lib.sic_rates(p, g, noise_power)
         for row, t in enumerate(ts):
             powers[t] = p[row]
@@ -616,7 +618,10 @@ def _greedy_rounds_jax_fused(
             scorer=scorer, shards=shards,
         )
         # the one host sync per schedule
-        assign_np, done_np, avail_np = jax.device_get((assign, done, avail))
+        with spans.span("fl.sync"):
+            assign_np, done_np, avail_np = jax.device_get(
+                (assign, done, avail)
+            )
     for t in np.flatnonzero(done_np):
         rounds[t] = tuple(int(d) for d in assign_np[t])
     return _jax_greedy_tail(

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import spans
+
 EVAL_SEED_OFFSET = 23
 # decorrelates the eval-sampling stream from the model-init / channel /
 # scheduling streams that consume FLConfig.seed (the scheduling permutation
@@ -158,7 +160,9 @@ class ClientBank:
         projected = m * nb * bs * (feat * itemsize + lab * 4)
         _check_bank_memory(projected, mem_fraction)
         xb, yb = _padded_arrays(x_train, y_train, shards, bs, nb)
-        return cls(xb=jnp.asarray(xb), yb=jnp.asarray(yb), sizes=sizes)
+        bank = cls(xb=jnp.asarray(xb), yb=jnp.asarray(yb), sizes=sizes)
+        spans.count("bank.bytes_uploaded", bank.nbytes)
+        return bank
 
 
 @dataclasses.dataclass
@@ -265,9 +269,11 @@ class BucketedClientBank:
                 x_train, y_train, [shards[k] for k in members], bs, nb
             )
             buckets.append((jnp.asarray(xb), jnp.asarray(yb)))
-        return cls(
+        bank = cls(
             buckets=buckets, bucket_of=bucket_of, row_of=row_of, sizes=sizes
         )
+        spans.count("bank.bytes_uploaded", bank.nbytes)
+        return bank
 
 
 @dataclasses.dataclass
@@ -287,9 +293,16 @@ class EvalBank:
     def num_samples(self) -> int:
         return self.xe.shape[0]
 
+    @property
+    def nbytes(self) -> int:
+        """Device bytes the bank holds (both tensors)."""
+        return int(self.xe.nbytes) + int(self.ye.nbytes)
+
     @classmethod
     def build(cls, x_test: np.ndarray, y_test: np.ndarray) -> "EvalBank":
-        return cls(xe=jnp.asarray(x_test), ye=jnp.asarray(y_test))
+        bank = cls(xe=jnp.asarray(x_test), ye=jnp.asarray(y_test))
+        spans.count("bank.bytes_uploaded", bank.nbytes)
+        return bank
 
 
 def eval_sample_plan(

@@ -7,7 +7,7 @@ Three layers:
   * the repo tree itself must scan clean (the same gate CI runs);
   * unit tests for the judgment calls the rules encode: suppression
     comments, module-attribute vs bound-method disambiguation for FLC001,
-    and jit-reachability for FLC003.
+    and jit-reachability for FLC003 and FLC008.
 """
 import os
 import textwrap
@@ -160,3 +160,27 @@ def test_pinned_fragments_are_long_literals():
     for const in ("ERR_OTA_TOPK", "ERR_OTA_COMPRESSION", "ERR_OTA_MAPEL",
                   "ERR_OTA_ALIGN_UPLINK", "ERR_SCAN_ONLINE_POLICY"):
         assert const in fragments.values()
+
+
+def test_flc008_needs_jit_reachability(tmp_path):
+    src = textwrap.dedent("""
+        import jax
+        import jax.numpy as jnp
+        from repro.utils import spans
+
+        def helper(x):
+            with spans.span("fl.step"):
+                return jnp.sum(x)
+
+        def driver(x):
+            with spans.span("fl.horizon"):
+                return helper(x)
+    """)
+    assert _scan(tmp_path, src) == []
+    # the same helper reached from a jit root: the span would time tracing
+    diags = _scan(tmp_path, src + textwrap.dedent("""
+        @jax.jit
+        def root(x):
+            return helper(x)
+    """))
+    assert [(d.line, d.rule) for d in diags] == [(7, "FLC008")]

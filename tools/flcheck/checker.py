@@ -33,6 +33,10 @@ flagged node spans), and grounded in a bug this repo actually shipped:
   FLC007  ``import hypothesis`` / ``import zstandard`` outside a
           ``try/except ImportError`` shim — the offline CI container does
           not ship either wheel (see requirements-dev.txt).
+  FLC008  a host span or counter (``spans.span`` / ``spans.count`` /
+          ``jax.profiler.TraceAnnotation``) inside jit-reachable code
+          (FLC003's call graph): it runs once per trace, so the span times
+          the tracing and the count counts compiles, not work.
 """
 from __future__ import annotations
 
@@ -74,6 +78,11 @@ RULES = {
         "hypothesis/zstandard imported outside the try/except "
         "optional-dependency shim (offline CI has neither wheel)"
     ),
+    "FLC008": (
+        "host span or counter inside jit-reachable code runs once per "
+        "trace, not per call; move it to the host code around the jitted "
+        "call"
+    ),
 }
 
 _SUPPRESS_RE = re.compile(
@@ -105,6 +114,10 @@ _OPTIONAL_DEPS = {"hypothesis", "zstandard"}                  # FLC007
 _LOG_FUNCS = {"jax.numpy.log", "numpy.log", "math.log"}       # FLC005
 _EXP_FUNCS = {"jax.numpy.exp", "numpy.exp", "math.exp"}       # FLC005
 _JNP_CTORS = {"jax.numpy.asarray", "jax.numpy.array"}         # FLC004
+_SPAN_CALLS = {                                               # FLC008
+    "repro.utils.spans.span", "repro.utils.spans.count",
+    "jax.profiler.TraceAnnotation",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,7 +271,8 @@ class _FuncInfo:
     path: str
     is_root: bool = False
     calls: set = dataclasses.field(default_factory=set)    # callee keys
-    candidates: list = dataclasses.field(default_factory=list)  # (line, desc)
+    # (line, rule, desc): FLC003/FLC008 sites, kept if jit-reachable
+    candidates: list = dataclasses.field(default_factory=list)
 
 
 def _contains_traced_call(node: ast.AST, ctx: _FileContext,
@@ -507,21 +521,23 @@ class _Visitor(ast.NodeVisitor):
                 if _is_const_one(arg.left) or _is_const_one(arg.right):
                     self._emit(node, "FLC005")
 
-        # FLC003 candidates (validated against jit-reachability later)
+        # FLC003/FLC008 candidates (validated against jit-reachability later)
         if cur is not None:
             traced = self._traced_stack[-1] if self._traced_stack else set()
             if (isinstance(node.func, ast.Name)
                     and node.func.id in _HOST_CASTS and node.args
                     and not _is_static_safe(node.args[0])
                     and _contains_traced_call(node.args[0], ctx, traced)):
-                cur.candidates.append((node.lineno, node.func.id))
+                cur.candidates.append((node.lineno, "FLC003", node.func.id))
             elif (isinstance(node.func, ast.Attribute)
                     and node.func.attr == "item" and not node.args):
-                cur.candidates.append((node.lineno, ".item()"))
+                cur.candidates.append((node.lineno, "FLC003", ".item()"))
             elif (dotted in ("numpy.asarray", "numpy.array") and node.args
                     and not _is_static_safe(node.args[0])
                     and _contains_traced_call(node.args[0], ctx, traced)):
-                cur.candidates.append((node.lineno, "np.asarray"))
+                cur.candidates.append((node.lineno, "FLC003", "np.asarray"))
+            elif dotted in _SPAN_CALLS:
+                cur.candidates.append((node.lineno, "FLC008", dotted))
 
         # transform calls: function-valued args become FLC003 roots
         if dotted in _TRACING_TRANSFORMS:
@@ -638,9 +654,10 @@ def check_paths(paths, *, search_dirs=("src", "."),
                 fragments: "dict | None" = None) -> list:
     """Run all rules over the given files/directories; returns Diagnostics.
 
-    Local rules apply per file; FLC003 resolves jit-reachability over the
-    union call graph of every scanned file, so cross-module reachability
-    (driver in one module, traced helper in another) is honored.
+    Local rules apply per file; FLC003 and FLC008 resolve jit-reachability
+    over the union call graph of every scanned file, so cross-module
+    reachability (driver in one module, traced helper in another) is
+    honored.
     """
     files = []
     for p in paths:
@@ -670,12 +687,10 @@ def check_paths(paths, *, search_dirs=("src", "."),
                 merged.is_root = merged.is_root or info.is_root
                 merged.calls |= info.calls
                 merged.candidates.extend(
-                    (ln, d, info.path) for ln, d in info.candidates
+                    (*c, info.path) for c in info.candidates
                 )
             else:
-                info.candidates = [
-                    (ln, d, info.path) for ln, d in info.candidates
-                ]
+                info.candidates = [(*c, info.path) for c in info.candidates]
                 funcs[key] = info
 
     reach = _reachable(funcs)
@@ -685,11 +700,11 @@ def check_paths(paths, *, search_dirs=("src", "."),
         info = funcs.get(key)
         if info is None:
             continue
-        for ln, desc, path in info.candidates:
+        for ln, rule, desc, path in info.candidates:
             sup = _suppressed_rules(lines_of.get(path, []), ln, ln)
-            if "*" in sup or "FLC003" in sup:
+            if "*" in sup or rule in sup:
                 continue
             diags.append(Diagnostic(
-                path, ln, "FLC003", f"{RULES['FLC003']} [{desc}]"
+                path, ln, rule, f"{RULES[rule]} [{desc}]"
             ))
     return sorted(set(diags), key=lambda d: (d.path, d.line, d.rule))
