@@ -28,6 +28,7 @@ sorting) we fix the decode order by channel gain, strongest first.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -72,7 +73,12 @@ def feasible(z: np.ndarray, gains_sorted, pmax, noise_power) -> bool:
     return bool(np.all(p <= pmax * (1.0 + 1e-12)))
 
 
-def _project(z: np.ndarray, gains_sorted, pmax, noise_power, tol=1e-12):
+# _project's bisection stops once hi - lo < tol: 40 levels.
+_BISECTION_TOL = 1e-12
+
+
+def _project(z: np.ndarray, gains_sorted, pmax, noise_power,
+             tol=_BISECTION_TOL):
     """MAPEL projection: largest lam in (0,1] with 1 + lam*(z-1) feasible.
 
     We project along the ray in (z - 1) (= SINR) space which keeps the
@@ -243,29 +249,79 @@ def _min_powers_batched(z_gk, gains_gk_sorted, noise_power) -> np.ndarray:
     return p
 
 
-def _feasible_batched(z_gk, gains_gk_sorted, pmax, noise_power) -> np.ndarray:
-    ok = ~np.any(z_gk < 1.0, axis=1)
-    p = _min_powers_batched(z_gk, gains_gk_sorted, noise_power)
-    return ok & np.all(p <= pmax * (1.0 + 1e-12), axis=1)
+# Bisection levels decided per feasibility pass of _project_batched.
+_LEVELS_PER_PASS = 4
 
 
-def _project_batched(z_gk, gains_gk_sorted, pmax, noise_power, tol=1e-12):
-    """Row-wise _project: one shared bisection, rows freeze at their own tol
-    step so each row reproduces the scalar bisection's early break exactly."""
-    g = z_gk.shape[0]
-    lo, hi = np.zeros(g), np.ones(g)
-    active = np.ones(g, dtype=bool)
-    for _ in range(80):
-        if not active.any():
-            break
-        mid = 0.5 * (lo + hi)
-        feas = _feasible_batched(
-            1.0 + mid[:, None] * (z_gk - 1.0), gains_gk_sorted, pmax, noise_power
-        )
-        lo = np.where(active & feas, mid, lo)
-        hi = np.where(active & ~feas, mid, hi)
-        active = active & ((hi - lo) >= tol)
-    return 1.0 + lo[:, None] * (z_gk - 1.0)
+def _bisection_levels(tol) -> int:
+    """Levels _project's bisection runs: hi - lo is 2**-n after level n, and
+    it stops after the first n with 2**-n < tol, or after 80."""
+    n = 1
+    while n < 80 and 2.0 ** -n >= tol:
+        n += 1
+    return n
+
+
+@functools.cache
+def _walk_table(d: int) -> np.ndarray:
+    """The sequential bisection's path through d levels, as a table: entry
+    ``code`` (bit j-1 set iff mid j of the 2**d - 1 is feasible) is the j
+    at which lo ends, reading only the bits of the mids it tests."""
+    codes = np.arange(2 ** (2 ** d - 1))
+    j = np.zeros(codes.size, dtype=np.intp)
+    half = 2 ** (d - 1)
+    while half:
+        j += half * ((codes >> (j + half - 1)) & 1)
+        half //= 2
+    return j
+
+
+def _project_batched(z_gk, gains_gk_sorted, pmax, noise_power,
+                     tol=_BISECTION_TOL):
+    """Row-wise _project, bit for bit, deciding _LEVELS_PER_PASS levels per
+    feasibility pass.
+
+    After n levels of _project's bisection lo is a multiple of 2**-n and
+    hi = lo + 2**-n, so every mid the next d levels can test is
+    lo + j * 2**-(n+d), j = 1 .. 2**d - 1: exact in float64 (n + d <= 52)
+    and equal to the sequential 0.5 * (lo + hi).  One pass tests all of
+    them; the walk (:func:`_walk_table`) then reads the feasibility of
+    exactly the mids the sequential loop would have tested, so no
+    monotonicity is assumed.
+    """
+    levels = _bisection_levels(tol)
+    if levels > 52:
+        raise ValueError(f"tol={tol} needs {levels} bisection levels; at most "
+                         "52 keep every mid exact in float64")
+    dz = z_gk - 1.0
+    dz_k = dz.T[:, :, None]                                     # (K, rows, 1)
+    g2 = (gains_gk_sorted * gains_gk_sorted).T[:, :, None]      # see
+    cap = pmax * (1.0 + 1e-12)                                  # feasible()
+    # with no dz < 0 every z = 1 + mid*dz (mid > 0) is >= 1 or NaN
+    below = bool(np.any(dz < 0.0))
+    lo = np.zeros(z_gk.shape[0])
+    done = 0
+    while done < levels:
+        d = min(_LEVELS_PER_PASS, levels - done)
+        step = 2.0 ** -(done + d)
+        mids = lo[:, None] + np.arange(1, 2 ** d) * step        # (rows, J)
+        z = 1.0 + mids * dz_k                                   # (K, rows, J)
+        # feasible(): every z >= 1 and the min powers (back-substitution
+        # of min_powers_for_targets) inside the box
+        if below:
+            feas = ~np.any(z < 1.0, axis=0)
+        else:
+            feas = np.ones(mids.shape, dtype=bool)
+        interference = noise_power
+        for i in range(len(z) - 1, -1, -1):
+            p = (z[i] - 1.0) * interference / g2[i]
+            feas &= p <= cap
+            if i:
+                interference = interference + p * g2[i]
+        code = feas @ (1 << np.arange(2 ** d - 1))
+        lo = lo + _walk_table(d)[code] * step
+        done += d
+    return 1.0 + lo[:, None] * dz
 
 
 def _z_of_powers_batched(p_gk, gains_gk_sorted, noise_power) -> np.ndarray:
@@ -328,11 +384,13 @@ def mapel_batched(
     ``[mapel(g_i, w_i, ...) for i]`` (tests assert bit equality).
 
     The schedulers' finalization path uses this to refine the power
-    allocation of all T selected groups in one call: the polyblock vertex
-    bookkeeping stays per group (it is data dependent), but the hot inner
-    loops — the 80-step projection bisections, the feasibility
-    back-substitutions, and the coordinate-ascent polish grid — run
-    vectorized across every still-active group.
+    allocation of all T selected groups in one call.  Every lockstep step
+    is a fixed number of whole-array calls, whatever the number of active
+    groups, their vertices, or the bisection depth: the polyblocks live in
+    one vertex store with cached objectives, every active group pops,
+    splits and prunes at once, and the projections of all groups that
+    popped go through one :func:`_project_batched` call.  The polish grid
+    runs vectorized across the groups too.
 
     gains_gk / weights_gk: (G, K) rows in arbitrary (input) order; returns
     powers in the same per-row input order.
@@ -344,7 +402,7 @@ def mapel_batched(
         return _counted(BatchedPowerSolution(
             np.zeros((g_cnt, k_cnt)), np.zeros(g_cnt),
             np.zeros(g_cnt, dtype=int), np.zeros(g_cnt),
-        ), eps, max_iter)
+        ), eps, max_iter, 0)
     order = np.argsort(-gains, axis=1, kind="stable")   # strongest first
     g = np.take_along_axis(gains, order, axis=1)
     w = np.take_along_axis(weights, order, axis=1)
@@ -357,11 +415,11 @@ def mapel_batched(
         np.put_along_axis(powers, order, p_sorted, axis=1)
         return _counted(BatchedPowerSolution(
             powers, rate, np.zeros(g_cnt, dtype=int), np.zeros(g_cnt)
-        ), eps, max_iter)
+        ), eps, max_iter, 0)
 
     z_top = 1.0 + pmax * g**2 / noise_power
-    verts = [[z_top[i]] for i in range(g_cnt)]
     best_z = _project_batched(z_top, g, pmax, noise_power)
+    projections = 1
     best_val = _objective_rows(best_z, w)
     z_corner = _z_of_powers_batched(np.full((g_cnt, k_cnt), pmax), g, noise_power)
     corner_val = _objective_rows(z_corner, w)
@@ -369,46 +427,70 @@ def mapel_batched(
     best_z = np.where(take[:, None], z_corner, best_z)
     best_val = np.where(take, corner_val, best_val)
 
+    # The polyblock of group i is its row of the vertex store: verts[i, s]
+    # and its objective vals[i, s] fill slots in mapel()'s list order, and
+    # popping or pruning a vertex sets its value to -inf (objectives are
+    # exp(...) >= 0 or NaN, never -inf), so the survivors keep their order
+    # and np.argmax over a row picks the list's first maximum.  A step
+    # adds at most K vertices a group; the store doubles when full.
+    cap = 1 + k_cnt * min(max_iter, 16)
+    verts = np.empty((g_cnt, cap, k_cnt))
+    vals = np.full((g_cnt, cap), -np.inf)
+    verts[:, 0] = z_top
+    vals[:, 0] = _objective_rows(z_top, w)
+    used = np.ones(g_cnt, dtype=np.intp)       # next free slot per group
+    diag = np.arange(k_cnt)
+
     it = np.zeros(g_cnt, dtype=int)
     gap = np.full(g_cnt, np.inf)
     done = np.zeros(g_cnt, dtype=bool)
     while True:
-        active = [
-            i for i in range(g_cnt) if not done[i] and it[i] < max_iter and verts[i]
-        ]
-        if not active:
+        top = int(used.max())
+        act = np.flatnonzero(
+            ~done & (it < max_iter) & np.any(vals[:, :top] != -np.inf, axis=1)
+        )
+        if act.size == 0:
             break
-        popped = []
-        for i in active:
-            it[i] += 1
-            vals = _objective_rows(np.asarray(verts[i]), w[i])
-            j = int(np.argmax(vals))
-            v = verts[i].pop(j)
-            ub = float(vals[j])
-            gap[i] = (ub - best_val[i]) / max(best_val[i], 1e-12)
-            if gap[i] <= eps:
-                done[i] = True
-            else:
-                popped.append((i, v))
-        if not popped:
+        it[act] += 1
+        va = vals[act, :top]
+        j = np.argmax(va, axis=1)
+        ub = va[np.arange(act.size), j]
+        v = verts[act, j]
+        vals[act, j] = -np.inf                 # pop
+        bv = best_val[act]
+        gap[act] = (ub - bv) / np.maximum(bv, 1e-12)
+        conv = gap[act] <= eps
+        done[act[conv]] = True
+        ip = act[~conv]
+        if ip.size == 0:
             continue
-        idxs = np.asarray([i for i, _ in popped])
-        zs = np.stack([v for _, v in popped])
-        projs = _project_batched(zs, g[idxs], pmax, noise_power)
-        vals_p = _objective_rows(projs, w[idxs])
-        for (i, v), proj, val in zip(popped, projs, vals_p):
-            if val > best_val[i]:
-                best_val[i], best_z[i] = val, proj
-            for j in range(k_cnt):
-                if proj[j] < v[j] - 1e-12:
-                    nv = v.copy()
-                    nv[j] = proj[j]
-                    verts[i].append(nv)
-            if verts[i]:
-                keep = _objective_rows(np.asarray(verts[i]), w[i]) > best_val[i] * (
-                    1 + eps / 4
-                )
-                verts[i] = [u for u, kp in zip(verts[i], keep) if kp]
+        v = v[~conv]
+        proj = _project_batched(v, g[ip], pmax, noise_power)
+        projections += 1
+        val = _objective_rows(proj, w[ip])
+        better = val > best_val[ip]
+        best_val[ip] = np.where(better, val, best_val[ip])
+        best_z[ip] = np.where(better[:, None], proj, best_z[ip])
+        # split v_j -> proj_j along each coordinate j, appended in j order
+        split = proj < v - 1e-12                                # (P, K)
+        nv = np.repeat(v[:, None, :], k_cnt, axis=1)            # (P, K, K)
+        nv[:, diag, diag] = proj
+        slot = used[ip, None] + np.cumsum(split, axis=1) - 1
+        used[ip] += split.sum(axis=1)
+        if used.max() > cap:
+            verts = np.concatenate([verts, np.empty_like(verts)], axis=1)
+            vals = np.concatenate([vals, np.full_like(vals, -np.inf)], axis=1)
+            cap *= 2
+        rows = np.broadcast_to(ip[:, None], split.shape)[split]
+        nv = nv[split]
+        verts[rows, slot[split]] = nv
+        vals[rows, slot[split]] = _objective_rows(nv, w[rows])
+        # prune the vertices that cannot beat the incumbent
+        top = int(used.max())
+        vp = vals[ip, :top]
+        vals[ip, :top] = np.where(
+            vp > (best_val[ip] * (1 + eps / 4))[:, None], vp, -np.inf
+        )
 
     p_sorted = np.minimum(_min_powers_batched(best_z, g, noise_power), pmax)
     cand_a = _polish_batched(p_sorted, g, w, pmax, noise_power)
@@ -420,18 +502,22 @@ def mapel_batched(
     powers = np.zeros((g_cnt, k_cnt))
     np.put_along_axis(powers, order, p_fin, axis=1)
     rate = rates_lib.batched_weighted_rates(powers, gains, weights, noise_power)
+    passes = -(-_bisection_levels(_BISECTION_TOL) // _LEVELS_PER_PASS)
     return _counted(
         BatchedPowerSolution(powers, rate, it, np.maximum(gap, 0.0)),
-        eps, max_iter,
+        eps, max_iter, projections * passes,
     )
 
 
-def _counted(sol: BatchedPowerSolution, eps, max_iter):
+def _counted(sol: BatchedPowerSolution, eps, max_iter, passes):
     """Add one solve to the ``power.*`` counters (``repro.utils.spans``):
-    groups, polyblock iterations, and groups stopped at ``max_iter`` short
-    of ``eps``."""
+    groups, polyblock iterations, lockstep steps (the most iterations of
+    any group), batched feasibility passes of the projections, and groups
+    stopped at ``max_iter`` short of ``eps``."""
     spans.count("power.mapel_groups", len(sol.iterations))
     spans.count("power.mapel_iters", int(np.sum(sol.iterations)))
+    spans.count("power.mapel_steps", int(np.max(sol.iterations, initial=0)))
+    spans.count("power.mapel_feasibility_passes", passes)
     spans.count("power.mapel_unconverged", int(np.sum(
         (sol.iterations >= max_iter) & (sol.gaps > eps)
     )))

@@ -130,6 +130,90 @@ def test_mapel_batched_near_zero_gains_matches_sequential():
         assert batched.gaps[i] == seq.gap
 
 
+def _paper_cell_groups(k, seed=0, groups=35):
+    """Groups of the paper's cell (CellConfig: 10-500 m, path-loss exponent
+    3, Rayleigh fading): one random K-device group per round, weighted by
+    their shard fractions."""
+    import jax
+
+    from repro.core import channel
+
+    cell = channel.CellConfig()
+    key = jax.random.PRNGKey(seed)
+    dist = channel.sample_positions(jax.random.fold_in(key, 1), cell)
+    gains_tm = np.asarray(channel.sample_round_channels(
+        jax.random.fold_in(key, 2), dist, cell, groups), dtype=np.float64)
+    rng = np.random.default_rng(seed)
+    sizes = rng.lognormal(0.0, 0.4, cell.num_devices)
+    devs = np.stack([rng.choice(cell.num_devices, k, replace=False)
+                     for _ in range(groups)])
+    return (gains_tm[np.arange(groups)[:, None], devs],
+            (sizes / sizes.sum())[devs], cell)
+
+
+@pytest.mark.parametrize("k,max_iter", [(3, 300), (4, 60), (2, 3), (3, 20)])
+def test_mapel_batched_matches_sequential_at_paper_cell_shape(k, max_iter):
+    """35 groups of the paper's cell: vertex lists grow to hundreds (the
+    store's capacity doubles), some groups converge and some stop at
+    ``max_iter``, and every row is still mapel() bit for bit."""
+    gains, w, cell = _paper_cell_groups(k)
+    pmax, noise = cell.max_power_w, cell.noise_power_w
+    batched = power.mapel_batched(gains, w, pmax, noise, max_iter=max_iter)
+    stopped = (batched.iterations >= max_iter) & (batched.gaps > 1e-3)
+    assert stopped.any() and not stopped.all()
+    for i in range(len(gains)):
+        seq = power.mapel(gains[i], w[i], pmax, noise, max_iter=max_iter)
+        np.testing.assert_array_equal(batched.powers[i], seq.powers)
+        assert batched.weighted_rates[i] == seq.weighted_rate
+        assert batched.iterations[i] == seq.iterations
+        assert batched.gaps[i] == seq.gap
+
+
+def _projection_rows(case):
+    """(z, gains sorted strongest first) rows for one projection edge case."""
+    rng = np.random.default_rng(17)
+    g = np.sort(np.abs(rng.normal(1e-6, 5e-7, (4, 3))) + 1e-8, axis=1)[:, ::-1]
+    z_top = 1.0 + PMAX * g * g / NOISE
+    if case == "z_one":
+        return np.ones((1, 3)), g[:1]
+    if case == "unit_coordinate":
+        z = 1.0 + np.array([[0.0, 0.7, 0.4], [0.9, 0.3, 0.0]]) * (z_top[:2] - 1.0)
+        return z, g[:2]
+    if case == "near_zero_gains":
+        g0 = np.abs(np.random.default_rng(13).normal(1e-12, 5e-13, (5, 3))) + 1e-15
+        g0[2, 0] = 1e-6
+        g0[4] = 1e-15
+        g0 = np.sort(g0, axis=1)[:, ::-1]
+        return 1.0 + PMAX * g0 * g0 / NOISE, g0
+    if case == "infeasible_first_mid":
+        return 1.0 + 3.0 * (z_top - 1.0), g
+    if case == "below_one":
+        z = z_top.copy()
+        z[:2, 1] = 1.0 - 1e-9
+        return z, g
+    parts = [_projection_rows(c) for c in _PROJECTION_CASES[:-1]]
+    return (np.concatenate([z for z, _ in parts]),
+            np.concatenate([g for _, g in parts]))
+
+
+_PROJECTION_CASES = ("z_one", "unit_coordinate", "near_zero_gains",
+                     "infeasible_first_mid", "below_one", "mix")
+
+
+@pytest.mark.parametrize("tol", [1e-12, 1e-10])
+@pytest.mark.parametrize("case", _PROJECTION_CASES)
+def test_project_batched_matches_one_level_bisection(case, tol):
+    """The multi-level projection walks the one-level bisection's path: each
+    row equals _project's bit for bit, on rows where every mid is feasible,
+    none is, the first is not, the gains sit at the numerical floor, and a
+    target lies below 1; tol=1e-10 ends on a partial pass (34 levels)."""
+    z, g = _projection_rows(case)
+    got = power._project_batched(z, g, PMAX, NOISE, tol=tol)
+    for i in range(len(z)):
+        np.testing.assert_array_equal(
+            got[i], power._project(z[i], g[i], PMAX, NOISE, tol=tol))
+
+
 def test_mapel_gap_reported():
     gains, w = _instance(3, 7)
     sol = power.mapel(gains, w, PMAX, NOISE, eps=1e-3, max_iter=300)

@@ -21,11 +21,20 @@ def _added(before):
 
 
 @pytest.mark.parametrize("k,max_iter,eps", [(3, 300, 1e-3), (3, 4, 1e-6),
-                                            (2, 300, 1e-3), (1, 300, 1e-3)])
-def test_mapel_counters_sum_the_solution(k, max_iter, eps):
+                                            (2, 300, 1e-3), (1, 300, 1e-3),
+                                            (4, 300, 1e-3)])
+def test_mapel_counters_sum_the_solution(k, max_iter, eps, monkeypatch):
     rng = np.random.default_rng(k * 1000 + max_iter)
     gains = np.abs(rng.normal(1e-6, 5e-7, (6, k))) + 1e-8
     w = rng.dirichlet(np.ones(k), size=6)
+    projections = []
+    project = power._project_batched
+
+    def counted_project(*args, **kwargs):
+        projections.append(1)
+        return project(*args, **kwargs)
+
+    monkeypatch.setattr(power, "_project_batched", counted_project)
     before = spans.counts()
     sol = power.mapel_batched(gains, w, PMAX, NOISE, eps=eps,
                               max_iter=max_iter)
@@ -34,6 +43,11 @@ def test_mapel_counters_sum_the_solution(k, max_iter, eps):
     assert added.get("power.mapel_groups") == 6
     assert added.get("power.mapel_iters", 0) == int(np.sum(sol.iterations))
     assert added.get("power.mapel_unconverged", 0) == unconverged
+    # 40 bisection levels (tol 1e-12), _LEVELS_PER_PASS of them a pass
+    assert added.get("power.mapel_feasibility_passes", 0) == (
+        len(projections) * -(-40 // power._LEVELS_PER_PASS))
+    assert added.get("power.mapel_steps", 0) == int(np.max(sol.iterations))
+    assert (len(projections) > 0) == (k > 1)
     if max_iter == 4:     # the cut-short solve leaves groups unconverged
         assert unconverged > 0
 
