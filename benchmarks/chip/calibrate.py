@@ -25,7 +25,7 @@ def _seeds(text):
     return [int(s) for s in text.split(",") if s]
 
 
-def _faulted(entry, seeds_0):
+def _faulted(cell, entry, seeds_0):
     """The program's records of call 0 with each planted fault in turn."""
     import jax
 
@@ -34,7 +34,7 @@ def _faulted(entry, seeds_0):
     out = {}
     for name, plant in sorted(testing.FAULTS.items()):
         jax.clear_caches()
-        with plant():
+        with plant(cell):
             out[name] = [program.record(r, s)
                          for r, s in zip(entry.call(0), seeds_0)]
     jax.clear_caches()
@@ -63,7 +63,8 @@ def calibrate(cell, seeds, control_seeds, fault_seeds=(), *, calls=1,
             c0 = time.perf_counter()
             entry.call(n)
             call_s.append(time.perf_counter() - c0)
-        faulted = _faulted(entry, seeds_0) if seed in fault_seeds else {}
+        faulted = (_faulted(cell, entry, seeds_0) if seed in fault_seeds
+                   else {})
         for _, i in harness.checked(cell, [0], len(seeds_0), seed):
             row = {"workload": cell.name, "seed": seed,
                    "instance_seed": seeds_0[i]}

@@ -15,8 +15,10 @@ Everything a cell is made of is data found by name: ``BENCHMARK.json``
 names its configuration and traffic, ``configs/<config>.json`` holds the
 deployment, ``traffic/<traffic>.json`` the entry point and its settings,
 ``cells/<workload>.json`` the comparison's limits, ``entries/<entry>.py``
-drives the program, ``references/<reference>.py`` is the plain
-reference, and ``metrics/<metric>.py`` reads one per-layer metric.
+drives the program, ``worlds/<data.kind>.py`` makes the data,
+``references/<reference>.py`` is the plain reference (and counts the
+model's FLOPs and parameters), and ``metrics/<metric>.py`` reads one
+per-layer metric.
 """
 from __future__ import annotations
 
@@ -272,12 +274,13 @@ def run(cell, *, seed, seconds, trace, require_tpu=True, t0=None):
     del kept
     horizons = sum(len(call) for call in records)
     rounds = cell.config["fl"]["num_rounds"]
+    train_flops, eval_flops = reference(cell).sample_flops(cell.config)
     done_flops = sum(
         flops.horizon_flops(
             rec["devices"], world.sizes,
             epochs=cell.config["fl"]["local_epochs"],
             test_samples=len(world.dataset.y_test),
-            widths=tuple(cell.config["model"]["widths"]))
+            train_flops=train_flops, eval_flops=eval_flops)
         for call in records for rec in call)
 
     device = {"platform": devices[0].platform, "kind": kind,

@@ -1,6 +1,7 @@
 """Small cells for the CPU tests: a cell of ``BENCHMARK.json`` with its
-configuration cut to 24 devices, eight rounds and 1,200 images, and the
-program faults the tests plant under a run."""
+configuration cut to 24 devices, eight rounds and 1,200 samples, and the
+program faults the tests plant under a run.  A fault is made for a cell
+(``FAULTS[name](cell)``) and planted with ``with``."""
 from __future__ import annotations
 
 import contextlib
@@ -32,7 +33,7 @@ def patched(obj, attr, value):
         setattr(obj, attr, old)
 
 
-def _unchanged_state():
+def _unchanged_state(cell):
     """The round body returns the parameters it was given."""
     from repro.core import fl_engine
 
@@ -45,21 +46,22 @@ def _unchanged_state():
     return patched(fl_engine, "_train_quantize_aggregate", fault)
 
 
-def _half_batch():
-    """Each SGD step sees the first half of its minibatch, and the loss is
-    the mean over that half."""
-    from repro.models.fl_models import LenetFLModel
+def _half_batch(cell):
+    """Each SGD step of the cell's model sees the first half of its
+    minibatch, and the loss is the mean over that half."""
+    from repro.models.fl_models import get_fl_model
 
-    real = LenetFLModel.batch_loss
+    model = type(get_fl_model(cell.config["fl"]["model"]))
+    real = model.batch_loss
 
     def fault(self, params, bx, by, valid):
         h = bx.shape[0] // 2
         return real(self, params, bx[:h], by[:h], valid[:h])
 
-    return patched(LenetFLModel, "batch_loss", fault)
+    return patched(model, "batch_loss", fault)
 
 
-def _altered_schedule():
+def _altered_schedule(cell):
     """The scheduler's first round names another device than it chose:
     the first devices of rounds 0 and 1 trade places (precomputed plans),
     or round 0 lists a device the scan did not choose (online)."""
@@ -104,7 +106,7 @@ def run_tiny(name, seed, fault=None):
 
     cell = tiny_cell(name)
     jax.clear_caches()
-    ctx = FAULTS[fault]() if fault else contextlib.nullcontext()
+    ctx = FAULTS[fault](cell) if fault else contextlib.nullcontext()
     with ctx:
         return harness.run(cell, seed=seed, seconds=0.0, trace=False,
                            require_tpu=False)

@@ -1,10 +1,9 @@
-"""The benchmark's data: a MNIST-like set and its non-iid device shards,
-made from ``--seed`` alone.
+"""The benchmark's data: a world of one kind, made from ``--seed`` alone.
 
-The image generator is a copy of the repository's synthetic MNIST stand-in
-(``repro.data.mnist_like``): each class is a mixture of three Gaussian
-blobs on the 28 x 28 grid plus pixel noise.  It lives here so that the
-yardstick does not move when the program's own generator does.
+A configuration's ``data.kind`` (``images`` where it names none) picks the
+generator ``worlds/<kind>.py``, whose ``build(config, seed)`` returns a
+``World``: the training and test set and the devices' shards.  Every kind
+shards its training set by the one law here.
 
 The shards follow the Dirichlet(alpha) class-mixture protocol the program
 uses, with one change: the shard *sizes* are the same for every seed.
@@ -12,7 +11,7 @@ They are the quantiles of the log-normal size law, assigned to devices in
 a seeded order, so every seed gives the same padded bank and scan shapes
 (the batch count of the largest shard fixes them) and a fresh seed finds
 every program in the compile cache.  Which device holds which size, which
-classes it holds, and every pixel change with the seed.
+classes it holds, and every sample change with the seed.
 """
 from __future__ import annotations
 
@@ -24,8 +23,8 @@ import numpy as np
 
 @dataclasses.dataclass
 class Dataset:
-    x_train: np.ndarray  # (N, 784) float32 in [0, 1]
-    y_train: np.ndarray  # (N,) int32
+    x_train: np.ndarray  # (N, ...) one row a sample, in the kind's layout
+    y_train: np.ndarray  # (N, ...) int32 labels
     x_test: np.ndarray
     y_test: np.ndarray
 
@@ -35,51 +34,9 @@ class World:
     dataset: Dataset
     shards: list         # per-device index arrays into x_train
     sizes: np.ndarray    # (M,) shard sizes
-
-
-def _class_prototypes(rng, num_classes, blobs):
-    protos = []
-    for _ in range(num_classes):
-        cx = rng.uniform(5, 23, blobs)
-        cy = rng.uniform(5, 23, blobs)
-        sig = rng.uniform(1.5, 4.0, blobs)
-        amp = rng.uniform(0.6, 1.0, blobs)
-        protos.append((cx, cy, sig, amp))
-    return protos
-
-
-def _render(protos, rng, n):
-    cx, cy, sig, amp = protos
-    yy, xx = np.mgrid[0:28, 0:28]
-    imgs = np.zeros((n, 28, 28), np.float32)
-    for b in range(len(cx)):
-        jx = cx[b] + rng.normal(0, 1.2, n)
-        jy = cy[b] + rng.normal(0, 1.2, n)
-        js = sig[b] * np.exp(rng.normal(0, 0.15, n))
-        ja = amp[b] * np.exp(rng.normal(0, 0.2, n))
-        d2 = ((xx[None] - jx[:, None, None]) ** 2
-              + (yy[None] - jy[:, None, None]) ** 2)
-        imgs += ja[:, None, None] * np.exp(-d2 / (2 * js[:, None, None] ** 2))
-    imgs += rng.normal(0, 0.12, imgs.shape)
-    return np.clip(imgs, 0.0, 1.0).reshape(n, 784).astype(np.float32)
-
-
-def make_images(num_samples, train_frac, seed, num_classes=10):
-    """``num_samples`` images, an equal share per class, shuffled and split."""
-    rng = np.random.default_rng(seed)
-    protos = _class_prototypes(rng, num_classes, blobs=3)
-    per_class = num_samples // num_classes
-    xs, ys = [], []
-    for c in range(num_classes):
-        xs.append(_render(protos[c],
-                          np.random.default_rng([seed, c]), per_class))
-        ys.append(np.full(per_class, c, np.int32))
-    x = np.concatenate(xs)
-    y = np.concatenate(ys)
-    perm = rng.permutation(len(x))
-    x, y = x[perm], y[perm]
-    n_train = int(train_frac * len(x))
-    return Dataset(x[:n_train], y[:n_train], x[n_train:], y[n_train:])
+    config: dict = dataclasses.field(default=None, repr=False)
+    # the configuration the world was built for, so that a reference can
+    # read its model's sizes from it
 
 
 def shard_sizes(total, num_devices, size_sigma, min_per_device):
@@ -124,12 +81,14 @@ def dirichlet_shards(labels, num_devices, seed, *, alpha, size_sigma,
 
 
 def build_world(config, seed):
-    """The configuration's data and shards for one ``--seed``."""
-    data = config["data"]
-    ds = make_images(data["num_samples"], data["train_frac"], seed)
-    shards, sizes = dirichlet_shards(
-        ds.y_train, config["fl"]["num_devices"], seed,
-        alpha=data["alpha"], size_sigma=data["size_sigma"],
-        min_per_device=data["min_per_device"],
-    )
-    return World(ds, shards, np.asarray(sizes))
+    """The configuration's data and shards for one ``--seed``, from the
+    generator of its ``data.kind``."""
+    from chipbench import harness
+
+    kind = config["data"].get("kind", "images")
+    path = harness.BENCH_DIR / "worlds" / f"{kind}.py"
+    if not path.is_file():
+        raise KeyError(f"no world kind {kind!r}: {path} is missing")
+    world = harness.load_module(path).build(config, seed)
+    world.config = config
+    return world

@@ -7,7 +7,7 @@ import pytest
 from chipbench import compare, harness, testing, world
 
 CELLS = ("paper-noma.mapel-gwmin", "paper-noma.online-update-aware",
-         "ota.seed-sweep8")
+         "ota.seed-sweep8", "paper-noma.cell-sweep-4chip")
 
 
 @pytest.mark.parametrize("name", CELLS)

@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from chipbench import flops, harness
+from chipbench import harness
 
 ROOT = harness.ROOT
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -49,10 +49,11 @@ def test_configs_load():
         conf = json.loads((ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"]
         assert conf["source"] == c["source"]
-        assert (harness.BENCH_DIR / "references"
-                / f"{conf['reference']}.py").is_file()
-        assert flops.dense_params(tuple(conf["model"]["widths"])) == \
-            conf["model"]["params"]
+        ref = harness.load_module(harness.BENCH_DIR / "references"
+                                  / f"{conf['reference']}.py")
+        assert ref.param_count(conf) == conf["model"]["params"]
+        train, evaluated = ref.sample_flops(conf)
+        assert train > evaluated > 0
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])

@@ -75,6 +75,18 @@ def test_metrics_read_the_reduced_trace():
         assert _read(name, ctx) is None
 
 
+def test_round_body_reads_the_sharded_sweep():
+    """The cell sweep's scan runs as ``jit_fn`` on every chip at once: the
+    device time of all four counts, per instance-round."""
+    tr = xtrace.Trace(
+        ops=[E("fusion.1", 100, 40, c, "jit_fn") for c in range(4)],
+        modules=[E("jit_fn(7)", 100, 40, c) for c in range(4)],
+        spans=[E("bench.call", 0, 500)], chips=4)
+    ctx = types.SimpleNamespace(trace=tr, lo=0, hi=500, instance_rounds=16)
+    assert _read("round_body_device_ms", ctx) == pytest.approx(
+        4 * 40e-6 / 16)
+
+
 def test_json_round_trip():
     tr = _synthetic()
     back = xtrace.Trace.from_json(json.loads(json.dumps(tr.to_json())))
