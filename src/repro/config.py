@@ -18,7 +18,7 @@ def _round_up(x: int, m: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                      # dense | moe | ssm | hybrid | encdec | vlm | mlp
+    family: str                      # dense | moe | ssm | hybrid | pattern | encdec | vlm | mlp
     num_layers: int
     d_model: int
     num_heads: int
@@ -55,6 +55,16 @@ class ModelConfig:
     # vlm: one cross-attention layer every N self-attention layers
     cross_attn_every: int = 0
     num_image_tokens: int = 0
+    # pattern hybrid (granite-4.0-h): the kind of each layer in order
+    # ("mamba" | "attention"), every layer followed by a SwiGLU MLP of width
+    # d_ff, attention without positional encoding (NoPE), and the muP
+    # multipliers of the embedding, the residual branches and the logits
+    layer_types: tuple = ()
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0      # the logits are divided by this
+    attention_multiplier: Optional[float] = None  # softmax scale; None =
+                                                  # 1/sqrt(head_dim)
     # numerics
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -73,7 +83,8 @@ class ModelConfig:
 
     def padded_kv_heads(self, shards: int = 16) -> int:
         """KV heads replicated up to the shard count when kv < shards
-        (MaxText-style GQA replication; DESIGN.md §4)."""
+        (MaxText-style GQA replication: every shard of the head axis then
+        holds whole KV heads)."""
         if not self.num_kv_heads:
             return 0
         if self.num_kv_heads >= shards:
@@ -87,6 +98,8 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (approximate for exotic families)."""
+        if self.family == "pattern":
+            return self._pattern_param_count()
         d, v = self.d_model, self.padded_vocab
         emb = v * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
@@ -119,6 +132,23 @@ class ModelConfig:
         if self.family == "vlm" and self.cross_attn_every:
             n_cross = self.num_layers // self.cross_attn_every
             total += n_cross * (d * hd * (self.num_heads + 2 * self.num_kv_heads) + self.num_heads * hd * d + d)
+        return int(total)
+
+    def _pattern_param_count(self) -> int:
+        """Exact count of a pattern hybrid (``repro.models.pattern_hybrid``):
+        the tied embedding, each layer's mixer, two norms and MLP, and the
+        final norm."""
+        d, hd = self.d_model, self.resolved_head_dim
+        d_in = self.ssm_expand * d
+        nheads = d_in // self.ssm_head_dim
+        conv_dim = d_in + 2 * self.ssm_groups * self.ssm_state
+        mamba = (d * (d_in + conv_dim + nheads) + self.ssm_conv_width
+                 * conv_dim + conv_dim + 3 * nheads + d_in + d_in * d)
+        attn = 2 * d * hd * (self.num_heads + self.num_kv_heads)
+        total = self.vocab_size * d * (1 if self.tie_embeddings else 2) + d
+        for kind in self.layer_types:
+            total += ((mamba if kind == "mamba" else attn)
+                      + 3 * d * self.d_ff + 2 * d)
         return int(total)
 
     def active_param_count(self) -> int:

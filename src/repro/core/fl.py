@@ -19,8 +19,9 @@ Faithful paper-scale FedAvg over the simulated NOMA cell:
        compressed NOMA run against an uncompressed TDMA run would bias the
        Fig. 5 comparison).
     5. PS aggregates: theta^{t+1} = theta^t + sum_k w_k * dq(delta_k),
-       w_k = |D_k| / sum_selected |D_k| (weighted FedAvg; see DESIGN.md §6
-       on the paper's line-10 notation).
+       w_k = |D_k| / sum_selected |D_k|: weighted FedAvg, the usual
+       reading of the aggregate in the paper's Algorithm 1 (line 10), which
+       writes the sum over the scheduled devices without its weights.
   Timing: NOMA round = t_slot + T_d; TDMA round = K * t_slot + T_d (§IV).
 
 Two round-body engines implement steps 3-5, selected by
@@ -67,7 +68,7 @@ from repro.core import ota as ota_lib
 from repro.core import power as power_lib
 from repro.core import quantization as qlib
 from repro.data.client_bank import ClientBank, EvalBank, eval_sample_plan
-from repro.models.fl_models import get_fl_model
+from repro.models.fl_models import frozen_base, get_fl_model
 from repro.utils import spans
 from repro.utils.tree import tree_count
 
@@ -106,12 +107,13 @@ class FLResult:
 _sgd_epoch = jax.jit(fl_engine.sgd_epoch, static_argnames=("model", "unroll"))
 
 
-def local_update(params, xs, ys, cfg: FLConfig, model):
+def local_update(params, xs, ys, cfg: FLConfig, model, base=None):
     """Run local epochs; returns the model delta (new - old).
 
     Padding generalizes over the trailing feature/label shape: flat image
     rows with scalar labels, or (S,) token rows with (S,) shifted labels —
     pad positions always carry label -1, the shared validity convention.
+    ``base`` is the payload's frozen base (``fl_engine.bound``).
     """
     n = len(xs)
     bs = cfg.batch_size
@@ -123,13 +125,14 @@ def local_update(params, xs, ys, cfg: FLConfig, model):
     yb = jnp.asarray(yp.reshape(n_batches, bs, *ys.shape[1:]))
     new = params
     for _ in range(cfg.local_epochs):
-        new = _sgd_epoch(new, xb, yb, cfg.learning_rate, model=model)
+        new = _sgd_epoch(new, xb, yb, cfg.learning_rate, model=model,
+                         base=base)
     return jax.tree_util.tree_map(lambda a, b: a - b, new, params)
 
 
 def _legacy_round(
     params, devs, budgets, agg_w, dataset, shards, cfg: FLConfig, payload,
-    *, need_norms: bool, model, ota=None,
+    *, need_norms: bool, model, ota=None, base=None,
 ):
     """The per-device host round body (steps 3-5), kept as the oracle.
 
@@ -146,7 +149,8 @@ def _legacy_round(
     for j, d in enumerate(devs):
         idx = shards[d]
         delta = local_update(
-            params, dataset.x_train[idx], dataset.y_train[idx], cfg, model
+            params, dataset.x_train[idx], dataset.y_train[idx], cfg, model,
+            base,
         )
         if need_norms:
             # the policies' norm signal is the raw local update, taken
@@ -339,6 +343,7 @@ def run_federated_learning(
     model = get_fl_model(cfg.model)
     params = model.init(key)
     payload = tree_count(params) * 32  # I: full-precision payload bits
+    base = frozen_base(model)
 
     sizes = np.array([len(s) for s in shards], dtype=np.float64)
     weights = sizes / sizes.sum()
@@ -404,7 +409,8 @@ def run_federated_learning(
         # bound methods are fresh objects per attribute access, so
         # jax.jit(model.accuracy) here would recompile every run; the
         # engine's module-level jit (model as a static arg) caches properly
-        acc_fn = functools.partial(fl_engine._eval_full, model=model)
+        acc_fn = functools.partial(fl_engine._eval_full, model=model,
+                                   base=base)
 
     logs = []
     t_wall = 0.0
@@ -442,7 +448,7 @@ def run_federated_learning(
         else:
             params, bits_used, ratios, norms = _legacy_round(
                 params, devs, budgets, agg_w, dataset, shards, cfg, payload,
-                need_norms=need_norms, model=model, ota=ota_round,
+                need_norms=need_norms, model=model, ota=ota_round, base=base,
             )
         # empty rounds (T*K > M schedules legitimately produce empty tail
         # groups) train/aggregate nothing; the wall clock still advances and
@@ -601,22 +607,27 @@ def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
 def _horizon_statics(
     cfg: FLConfig, payload: int, eval_full: bool, cell, uplink,
 ) -> dict:
-    """The static kwargs of the fl_engine horizon programs, from the config.
+    """The static kwargs of the fl_engine horizon programs, from the config,
+    and the one operand they share: the payload's frozen base (None for a
+    payload that trains all its parameters; ``fl_engine.bound``), built on
+    the device the first time a process asks for it.
 
     The OTA statics are pinned to zeros outside the OTA uplink so a
     noma/tdma run never retraces when ota_noise/ota_threshold configs vary.
     """
     ota = uplink == "ota"
+    model = get_fl_model(cfg.model)
     return dict(
         lr=float(cfg.learning_rate), epochs=int(cfg.local_epochs),
         payload=int(payload), compress=cfg.compression == "adaptive",
         paper_exact=bool(cfg.paper_exact_range),
         use_pallas=bool(cfg.use_pallas), eval_full=bool(eval_full),
-        model=get_fl_model(cfg.model), topk=float(cfg.topk),
+        model=model, topk=float(cfg.topk),
         ota=ota,
         ota_noise=float(cfg.ota_noise) if ota else 0.0,
         ota_threshold=float(cfg.ota_threshold) if ota else 0.0,
         pmax=float(cell.max_power_w) if ota else 0.0,
+        base=frozen_base(model),
     )
 
 

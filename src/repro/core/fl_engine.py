@@ -86,7 +86,18 @@ HORIZON_MODES = ("per-round", "scan")
 # Shared local-SGD epoch (the single source of the per-client math)
 # --------------------------------------------------------------------------
 
-def sgd_epoch(params, x, y, lr, *, model, unroll: int = 1):
+def bound(params, base):
+    """What a payload's ``batch_loss`` and ``accuracy`` read: its
+    parameters, or ``(base, params)`` for a payload over a frozen base
+    (``fl_models.frozen_base``).  The base is an operand of every program
+    that trains or evaluates such a payload, passed and closed over but
+    never differentiated, carried, vmapped or aggregated; ``base=None``
+    (every payload that trains all its parameters) adds nothing to a
+    program."""
+    return params if base is None else (base, params)
+
+
+def sgd_epoch(params, x, y, lr, *, model, unroll: int = 1, base=None):
     """One pass of minibatch SGD over a device's (padded) shard.
 
     x: (n_batches, bs, ...); y: (n_batches, bs, ...) with -1 marking
@@ -101,13 +112,14 @@ def sgd_epoch(params, x, y, lr, *, model, unroll: int = 1):
     two paths apply the same update sequence.  ``unroll`` feeds
     ``lax.scan`` (numerics-neutral); the batched engine unrolls a few steps
     to cut the per-step loop overhead its one-dispatch round pays K-fold.
+    ``base`` is the payload's frozen base (see :func:`bound`).
     """
 
     def step(p, batch):
         bx, by, valid = batch
 
         def masked_loss(p_):
-            return model.batch_loss(p_, bx, by, valid)
+            return model.batch_loss(bound(p_, base), bx, by, valid)
 
         g = jax.grad(masked_loss)(p)
         new = jax.tree_util.tree_map(lambda w, gw: w - lr * gw, p, g)
@@ -204,7 +216,7 @@ def _sparse_quantize_aggregate(
 def _train_quantize_aggregate(
     params, x, y, budgets, agg_w, gains_k, noise_key,
     *, lr, epochs, payload, compress, paper_exact, use_pallas, need_norms,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """The round body on gathered client rows: vmapped local SGD -> norms ->
     traced per-client quantization -> weighted aggregation.
@@ -231,13 +243,17 @@ def _train_quantize_aggregate(
     compiler drops (dead inputs), so the digital paths trace the identical
     program they always did; bits are logged as 32 (analog — nothing is
     quantized on air).
+
+    ``base`` (a payload's frozen base, :func:`bound`) is shared by the K
+    clients: closed over by the vmapped SGD, not mapped.
     """
     k = x.shape[0]
 
     def local_delta(xk, yk):
         new = params
         for _ in range(epochs):
-            new = sgd_epoch(new, xk, yk, lr, model=model, unroll=8)
+            new = sgd_epoch(new, xk, yk, lr, model=model, unroll=8,
+                            base=base)
         return jax.tree_util.tree_map(lambda a, b: a - b, new, params)
 
     deltas = jax.vmap(local_delta)(x, y)        # leaves (K, ...)
@@ -336,7 +352,7 @@ _ROUND_STATICS = (
 def _round_step(
     params, xb, yb, dev_idx, budgets, agg_w, gains_k, noise_key,
     *, nb, lr, epochs, payload, compress, paper_exact, use_pallas, need_norms,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """gather -> shared round body (:func:`_train_quantize_aggregate`).
 
@@ -354,6 +370,7 @@ def _round_step(
         compress=compress, paper_exact=paper_exact, use_pallas=use_pallas,
         need_norms=need_norms, model=model, topk=topk, ota=ota,
         ota_noise=ota_noise, ota_threshold=ota_threshold, pmax=pmax,
+        base=base,
     )
 
 
@@ -361,7 +378,7 @@ def _round_step(
 def _round_step_gathered(
     params, x, y, budgets, agg_w, gains_k, noise_key,
     *, lr, epochs, payload, compress, paper_exact, use_pallas, need_norms,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """Round body on pre-gathered (K, nb, ...) rows — the bucketed-bank
     path, where the K-row gather spans several per-bucket banks and runs
@@ -373,6 +390,7 @@ def _round_step_gathered(
         compress=compress, paper_exact=paper_exact, use_pallas=use_pallas,
         need_norms=need_norms, model=model, topk=topk, ota=ota,
         ota_noise=ota_noise, ota_threshold=ota_threshold, pmax=pmax,
+        base=base,
     )
 
 
@@ -390,7 +408,7 @@ def _horizon_core(
     params, dev_tk, budgets_tk, agg_tk, gains_tk, keys_t, eval_mask_t,
     eval_idx_tn, xb, yb, xe, ye,
     *, lr, epochs, payload, compress, paper_exact, use_pallas, eval_full,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """One whole horizon as a single ``lax.scan`` over rounds.
 
@@ -413,6 +431,8 @@ def _horizon_core(
     :func:`run_horizon_vmapped` vmaps it over a seeds axis, and
     :func:`run_horizon_sharded` additionally shards a leading cell axis
     over a mesh — one implementation under all three transforms.
+    ``base`` (a payload's frozen base, :func:`bound`) is closed over by
+    the scan body: an operand of the program, not of the carry.
     """
 
     def body(p, inp):
@@ -424,13 +444,13 @@ def _horizon_core(
             compress=compress, paper_exact=paper_exact,
             use_pallas=use_pallas, need_norms=False, model=model, topk=topk,
             ota=ota, ota_noise=ota_noise, ota_threshold=ota_threshold,
-            pmax=pmax,
+            pmax=pmax, base=base,
         )
 
         def ev(q):
             if eval_full:
-                return model.accuracy(q, xe, ye)
-            return model.accuracy(q, xe[eidx], ye[eidx])
+                return model.accuracy(bound(q, base), xe, ye)
+            return model.accuracy(bound(q, base), xe[eidx], ye[eidx])
 
         acc = jax.lax.cond(
             do_eval, ev, lambda q: jnp.asarray(jnp.nan, jnp.float32), p2
@@ -450,7 +470,7 @@ def run_horizon(
     params, dev_tk, budgets_tk, agg_tk, gains_tk, keys_t, eval_mask_t,
     eval_idx_tn, xb, yb, xe, ye,
     *, nb, lr, epochs, payload, compress, paper_exact, use_pallas, eval_full,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """One precomputed-schedule horizon, one dispatch (see _horizon_core).
 
@@ -466,7 +486,7 @@ def run_horizon(
         lr=lr, epochs=epochs, payload=payload, compress=compress,
         paper_exact=paper_exact, use_pallas=use_pallas, eval_full=eval_full,
         model=model, topk=topk, ota=ota, ota_noise=ota_noise,
-        ota_threshold=ota_threshold, pmax=pmax,
+        ota_threshold=ota_threshold, pmax=pmax, base=base,
     )
 
 
@@ -475,7 +495,7 @@ def run_horizon_vmapped(
     params_s, dev_stk, budgets_stk, agg_stk, gains_stk, keys_st, eval_mask_t,
     eval_idx_stn, xb, yb, xe, ye,
     *, nb, lr, epochs, payload, compress, paper_exact, use_pallas, eval_full,
-    model, topk, ota, ota_noise, ota_threshold, pmax,
+    model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """A whole seed sweep (S independent horizons), one dispatch.
 
@@ -494,6 +514,7 @@ def run_horizon_vmapped(
             paper_exact=paper_exact, use_pallas=use_pallas,
             eval_full=eval_full, model=model, topk=topk, ota=ota,
             ota_noise=ota_noise, ota_threshold=ota_threshold, pmax=pmax,
+            base=base,
         )
 
     return jax.vmap(one)(
@@ -547,19 +568,28 @@ def _sharded_horizon_fn(
     ))
 
 
+def _no_sharded_base(base):
+    if base is not None:
+        raise ValueError(
+            "a payload over a frozen base runs its horizons on one device: "
+            "the sharded cell sweep does not place the base on the mesh")
+
+
 def run_horizon_sharded(
     params_cs, dev_cstk, budgets_cstk, agg_cstk, gains_cstk, keys_cst,
     eval_mask_t, eval_idx_cstn, xb, yb, xe, ye,
     *, shards, nb, lr, epochs, payload, compress, paper_exact, use_pallas,
-    eval_full, model, topk, ota, ota_noise, ota_threshold, pmax,
+    eval_full, model, topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """A (C, S) cells-x-seeds sweep with the cell axis sharded over a mesh.
 
     C must be a multiple of ``shards`` (the fl.py driver pads and
     unpads).  With ``shards = 1`` this is exactly the double-vmapped
     single-device program, which the sharded-equality test pins the
-    multi-device meshes against.
+    multi-device meshes against.  A payload over a frozen base has no
+    sharded sweep: ``base`` must be None.
     """
+    _no_sharded_base(base)
     fn = _sharded_horizon_fn(
         int(shards), int(nb), float(lr), int(epochs), int(payload),
         bool(compress), bool(paper_exact), bool(use_pallas), bool(eval_full),
@@ -593,7 +623,7 @@ def _online_horizon_core(
     eval_idx_tn, xb, yb, xe, ye,
     *, scheduler, pcfg, uplink, budget_scale, need_norms, lr, epochs,
     payload, compress, paper_exact, use_pallas, eval_full, model, topk, ota,
-    ota_noise, ota_threshold, pmax,
+    ota_noise, ota_threshold, pmax, base=None,
 ):
     """One whole *online-policy* horizon as a single ``lax.scan``.
 
@@ -657,7 +687,7 @@ def _online_horizon_core(
             payload=payload, compress=compress, paper_exact=paper_exact,
             use_pallas=use_pallas, need_norms=need_norms, model=model,
             topk=topk, ota=ota, ota_noise=ota_noise,
-            ota_threshold=ota_threshold, pmax=pmax,
+            ota_threshold=ota_threshold, pmax=pmax, base=base,
         )
 
         scat = jnp.where(mask, dev, num_devices)   # padding -> OOB, dropped
@@ -671,8 +701,8 @@ def _online_horizon_core(
 
         def ev(q):
             if eval_full:
-                return model.accuracy(q, xe, ye)
-            return model.accuracy(q, xe[eidx], ye[eidx])
+                return model.accuracy(bound(q, base), xe, ye)
+            return model.accuracy(bound(q, base), xe[eidx], ye[eidx])
 
         acc = jax.lax.cond(
             do_eval, ev, lambda q: jnp.asarray(jnp.nan, jnp.float32), p2
@@ -695,7 +725,7 @@ def run_horizon_online(
     eval_idx_tn, xb, yb, xe, ye,
     *, nb, scheduler, pcfg, uplink, budget_scale, need_norms, lr, epochs,
     payload, compress, paper_exact, use_pallas, eval_full, model, topk, ota,
-    ota_noise, ota_threshold, pmax,
+    ota_noise, ota_threshold, pmax, base=None,
 ):
     """One online-policy horizon, one dispatch (see _online_horizon_core).
 
@@ -714,7 +744,7 @@ def run_horizon_online(
         epochs=epochs, payload=payload, compress=compress,
         paper_exact=paper_exact, use_pallas=use_pallas, eval_full=eval_full,
         model=model, topk=topk, ota=ota, ota_noise=ota_noise,
-        ota_threshold=ota_threshold, pmax=pmax,
+        ota_threshold=ota_threshold, pmax=pmax, base=base,
     )
 
 
@@ -724,7 +754,7 @@ def run_horizon_online_vmapped(
     eval_idx_stn, xb, yb, xe, ye,
     *, nb, scheduler, pcfg, uplink, budget_scale, need_norms, lr, epochs,
     payload, compress, paper_exact, use_pallas, eval_full, model, topk, ota,
-    ota_noise, ota_threshold, pmax,
+    ota_noise, ota_threshold, pmax, base=None,
 ):
     """An online-policy seed sweep (S independent horizons), one dispatch.
 
@@ -746,6 +776,7 @@ def run_horizon_online_vmapped(
             paper_exact=paper_exact, use_pallas=use_pallas,
             eval_full=eval_full, model=model, topk=topk, ota=ota,
             ota_noise=ota_noise, ota_threshold=ota_threshold, pmax=pmax,
+            base=base,
         )
 
     return jax.vmap(one)(params_s, solo_stm, gains_stm, keys_st, eval_idx_stn)
@@ -800,7 +831,7 @@ def run_horizon_online_sharded(
     weights_m, sizes_m, xb, yb, xe, ye,
     *, shards, nb, scheduler, pcfg, uplink, budget_scale, need_norms, lr,
     epochs, payload, compress, paper_exact, use_pallas, eval_full, model,
-    topk, ota, ota_noise, ota_threshold, pmax,
+    topk, ota, ota_noise, ota_threshold, pmax, base=None,
 ):
     """A (C, S) online-policy cells-x-seeds sweep, cell axis sharded.
 
@@ -808,6 +839,7 @@ def run_horizon_online_sharded(
     ``shards`` (the fl.py driver pads and unpads), and ``shards = 1`` is
     exactly the double-vmapped single-device program.
     """
+    _no_sharded_base(base)
     fn = _sharded_online_fn(
         int(shards), int(nb), scheduler, pcfg, uplink, float(budget_scale),
         bool(need_norms), float(lr), int(epochs), int(payload),
@@ -826,26 +858,27 @@ def run_horizon_online_sharded(
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("model",))
-def _eval_full(params, xe, ye, *, model):
-    return model.accuracy(params, xe, ye)
+def _eval_full(params, xe, ye, *, model, base=None):
+    return model.accuracy(bound(params, base), xe, ye)
 
 
 @functools.partial(jax.jit, static_argnames=("model",))
-def _eval_sampled(params, xe, ye, idx, *, model):
+def _eval_sampled(params, xe, ye, idx, *, model, base=None):
     """Client-sampled test accuracy: gather the round's eval rows, forward
     once — the ClientBank gather idiom applied to evaluation."""
-    return model.accuracy(params, xe[idx], ye[idx])
+    return model.accuracy(bound(params, base), xe[idx], ye[idx])
 
 
 class BatchedRoundEngine:
     """Round-body engine: builds the bank once, then one dispatch per round."""
 
     def __init__(self, dataset, shards, cfg, payload_bits: int, model=None):
-        from repro.models.fl_models import get_fl_model
+        from repro.models.fl_models import frozen_base, get_fl_model
 
         self.cfg = cfg
         self.payload = int(payload_bits)
         self.model = model if model is not None else get_fl_model(cfg.model)
+        self.base = frozen_base(self.model)
         bank_cls = (
             BucketedClientBank if cfg.client_bank == "bucketed" else ClientBank
         )
@@ -873,11 +906,11 @@ class BatchedRoundEngine:
         if self._eval_idx is None:
             return float(_eval_full(
                 params, self.eval_bank.xe, self.eval_bank.ye,
-                model=self.model,
+                model=self.model, base=self.base,
             ))
         return float(_eval_sampled(
             params, self.eval_bank.xe, self.eval_bank.ye,
-            jnp.asarray(self._eval_idx[t]), model=self.model,
+            jnp.asarray(self._eval_idx[t]), model=self.model, base=self.base,
         ))
 
     def run_round(
@@ -939,13 +972,13 @@ class BatchedRoundEngine:
             x, y = self.bank.gather(devs, nb)
             params, bits, kept, norms = _round_step_gathered(
                 params, x, y, budgets_dev, agg_dev, gains_dev, key_dev,
-                **statics
+                base=self.base, **statics
             )
         else:
             params, bits, kept, norms = _round_step(
                 params, self.bank.xb, self.bank.yb,
                 jnp.asarray(devs, jnp.int32), budgets_dev, agg_dev,
-                gains_dev, key_dev, nb=nb, **statics,
+                gains_dev, key_dev, nb=nb, base=self.base, **statics,
             )
         if compress and cfg.topk < 1.0:
             # honest sparse accounting: on-air size from the realized

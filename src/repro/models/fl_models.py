@@ -29,6 +29,16 @@ identical batched engine / scanned horizon.  Names:
     transformer-class payload the compression stack is pinned on)
   * ``"<arch_id>"`` / ``"<arch_id>:smoke"`` — any ``repro.configs`` id
     (e.g. ``qwen2_0_5b``), resolved lazily to its CONFIG / SMOKE variant.
+  * ``"granite-h-micro-lora"`` — federated LoRA over a frozen base of one
+    granite-4.0-h-micro period at published widths (:class:`LoRAFLModel`,
+    kind ``"token_rows"``); ``"granite-h-micro-lora:smoke"`` the same over
+    the reduced SMOKE base (tests).
+
+A payload over a frozen base (``frozen_base()``) uploads its adapters
+only: ``init`` and the engine's parameters are the adapters, and the
+engine hands the loss and the metric the pair ``(base, adapters)``
+(``fl_engine.bound``), the base a traced operand it never differentiates,
+carries, vmaps or sends.
 
 FLModel instances are frozen dataclasses (hashable), so they ride through
 ``jax.jit`` static args and the sharded-horizon ``lru_cache`` unchanged.
@@ -43,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.configs import granite_4_0_h_micro as granite
 from repro.models import lenet
 
 
@@ -81,7 +92,7 @@ class LenetFLModel:
 # Families whose ``forward(params, tokens, cfg)`` needs no extra modality
 # kwargs — the FL uplink path trains language-model-shaped payloads; vlm /
 # encdec need per-batch image/encoder features the ClientBank doesn't carry.
-_TOKEN_FAMILIES = ("dense", "moe", "ssm", "hybrid")
+_TOKEN_FAMILIES = ("dense", "moe", "ssm", "hybrid", "pattern")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +149,115 @@ class TokenFLModel:
         return jnp.sum(hit) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+BASE_SEED = 20251002
+# PRNGKey(BASE_SEED) is the root of every frozen base: a fixed function of
+# the model, as a released checkpoint is, and of no instance seed
+
+_BASES: dict = {}
+# frozen bases built in this process, keyed by the model config: built once on
+# the device and shared by every horizon and every call after
+
+
+@dataclasses.dataclass(frozen=True)
+class LoRAFLModel:
+    """Federated LoRA (arXiv:2106.09685; FedIT, arXiv:2305.05644) over a
+    frozen base of a :mod:`repro.models.pattern_hybrid` model.
+
+    Kind ``"token_rows"``: a sample is a row of S + 1 token ids and its
+    label one int per row (any value >= 0 trains; -1 marks a padding row);
+    the loss is the mean next-token NLL over the S positions of the real
+    rows, the metric the next-token top-1 accuracy.  Both read the head in
+    blocks of ``block`` tokens.  The adapters are ``rank`` r with scale
+    ``alpha / r``.
+
+    The base is ``init_params(pattern_hybrid.schema(cfg),
+    PRNGKey(BASE_SEED))``: leaf at path p from fold_in(key, crc32(p)),
+    the initializers of ``repro.models.params`` (matrices truncated normal
+    +-3 over sqrt(fan_in), the embedding normal x 0.02, norms and D ones,
+    conv bias zeros, A_log log U(1, 16), dt_bias softplus^-1 of dt
+    log-uniform on [1e-3, 1e-1]).  The adapters are
+    ``init_params(pattern_hybrid.lora_schema(cfg, rank), key)`` of the
+    instance key.
+    """
+
+    cfg: ModelConfig
+    name: str
+    rank: int = 8
+    alpha: float = 16.0
+    block: int = 512
+    kind: str = "token_rows"
+
+    def schema(self):
+        from repro.models import pattern_hybrid
+
+        return pattern_hybrid.lora_schema(self.cfg, self.rank)
+
+    def init(self, key: jax.Array):
+        from repro.models.params import init_params
+
+        return init_params(self.schema(), key)
+
+    def frozen_base(self):
+        """The base on the device, built the first time a process asks."""
+        from repro.utils import spans
+
+        with spans.span("fl.base"):
+            base = _BASES.get(self.cfg)
+            if base is None:
+                base = _build_base(self.cfg)
+                _BASES[self.cfg] = base
+                spans.count("payload.base_bytes_built", sum(
+                    x.nbytes for x in jax.tree_util.tree_leaves(base)))
+            return base
+
+    def _hidden(self, params, tokens):
+        from repro.models import pattern_hybrid
+
+        base, lora = params
+        return pattern_hybrid.hidden(base, tokens, self.cfg, lora=lora,
+                                     lora_scale=self.alpha / self.rank)
+
+    def _labels(self, x, real):
+        """Next-token targets of each row, -1 on the rows not ``real``."""
+        return jnp.where(real[:, None], x[:, 1:], -1)
+
+    def batch_loss(self, params, bx, by, valid):
+        from repro.models import pattern_hybrid
+
+        labels = self._labels(bx, valid > 0)
+        total = pattern_hybrid.token_nll(
+            params[0], self._hidden(params, bx[:, :-1]), labels, self.cfg,
+            block=self.block)
+        count = jnp.sum(labels >= 0).astype(jnp.float32)
+        return total / jnp.maximum(count, 1.0)
+
+    def accuracy(self, params, x, y):
+        from repro.models import pattern_hybrid
+
+        labels = self._labels(x, y >= 0)
+        hits = pattern_hybrid.token_hits(
+            params[0], self._hidden(params, x[:, :-1]), labels, self.cfg,
+            block=self.block)
+        count = jnp.sum(labels >= 0).astype(jnp.float32)
+        return hits.astype(jnp.float32) / jnp.maximum(count, 1.0)
+
+
+def _build_base(cfg):
+    from repro.models import pattern_hybrid
+    from repro.models.params import init_params
+
+    schema = pattern_hybrid.schema(cfg)
+    return jax.jit(functools.partial(init_params, schema))(
+        jax.random.PRNGKey(BASE_SEED))
+
+
+def frozen_base(model):
+    """``model``'s frozen base on the device, or None for a payload that
+    trains all its parameters."""
+    get = getattr(model, "frozen_base", None)
+    return None if get is None else get()
+
+
 TINY_TRANSFORMER = ModelConfig(
     name="fl-tiny-transformer", family="dense",
     num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
@@ -169,6 +289,17 @@ register_fl_model(
 register_fl_model(
     "tiny-transformer-1m",
     lambda: TokenFLModel(cfg=TINY_TRANSFORMER_1M, name="tiny-transformer-1m"),
+)
+
+
+register_fl_model(
+    "granite-h-micro-lora",
+    lambda: LoRAFLModel(cfg=granite.PERIOD, name="granite-h-micro-lora"),
+)
+register_fl_model(
+    "granite-h-micro-lora:smoke",
+    lambda: LoRAFLModel(cfg=granite.SMOKE, name="granite-h-micro-lora:smoke",
+                        block=32),
 )
 
 

@@ -4,8 +4,8 @@ Conventions:
   * activations layout (B, S, ...) with heads as (B, S, H, D) — MaxText-style.
   * all matmul params fp32, compute cast to bf16, reductions/softmax in fp32.
   * attention never materializes (S, S): online-softmax over KV chunks
-    (lax.scan), which is the TPU-native flash formulation at the XLA level
-    (DESIGN.md §3).
+    (lax.scan), which is the TPU-native flash formulation at the XLA level:
+    memory O(S * chunk) per head, so 32k-token prefills fit.
 """
 from __future__ import annotations
 
@@ -26,7 +26,7 @@ COMPUTE_DTYPE = jnp.bfloat16
 # Without explicit constraints the SPMD partitioner's strategy for the
 # residual stream is underconstrained and degrades with depth (measured:
 # 10 GiB -> 115 GiB of fp32 activation all-reduce going from 2 to 4 layers
-# on llama-3.2-vision/prefill_32k — EXPERIMENTS.md §Perf pair B). The
+# on llama-3.2-vision/prefill_32k in the dry-run). The
 # launcher installs a hook that pins: residual (B,S,D) -> (batch, None,
 # None); heads (B,S,H,K) -> (batch, None, tensor, None).
 # --------------------------------------------------------------------------
@@ -112,22 +112,28 @@ def chunked_attention(
     q_offset: int | jax.Array = 0,
     kv_chunk: int = 1024,
     kv_valid_len: Optional[jax.Array] = None,  # decode: #valid cache slots
+    scale: Optional[float] = None,             # None: 1/sqrt(D)
+    compute_dtype=None,                        # None: COMPUTE_DTYPE
 ) -> jax.Array:
     """Grouped-query online-softmax attention, O(Sq * chunk) memory.
 
     GQA is computed grouped — Q reshaped to (B, Sq, Hkv, G, D) — so KV heads
-    are never materialized repeated.
+    are never materialized repeated.  ``compute_dtype`` is the dtype of the
+    q/k/v operands and the running output (scores and the softmax sums are
+    float32 either way).
     """
+    cd = COMPUTE_DTYPE if compute_dtype is None else compute_dtype
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
     g = h // hkv
-    qf = q.reshape(b, sq, hkv, g, d).astype(COMPUTE_DTYPE)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    qf = q.reshape(b, sq, hkv, g, d).astype(cd)
+    scale = (1.0 / jnp.sqrt(d).astype(jnp.float32) if scale is None
+             else jnp.float32(scale))
 
     n_chunks = (sk + kv_chunk - 1) // kv_chunk
     pad = n_chunks * kv_chunk - sk
-    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(COMPUTE_DTYPE)
-    vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(COMPUTE_DTYPE)
+    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(cd)
+    vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(cd)
     kc = kp.reshape(b, n_chunks, kv_chunk, hkv, d).transpose(1, 0, 2, 3, 4)
     vc = vp.reshape(b, n_chunks, kv_chunk, hkv, d).transpose(1, 0, 2, 3, 4)
 
@@ -150,18 +156,18 @@ def chunked_attention(
         p = jnp.where(mask[None, None, None], p, 0.0)
         corr = jnp.where(jnp.isneginf(m_run), 0.0, jnp.exp(m_run - m_safe))
         l_new = l_run * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhgqc,bchd->bhgqd", p.astype(COMPUTE_DTYPE), v_blk)
-        acc = acc * corr[..., None].astype(COMPUTE_DTYPE) + pv
+        pv = jnp.einsum("bhgqc,bchd->bhgqd", p.astype(cd), v_blk)
+        acc = acc * corr[..., None].astype(cd) + pv
         return (m_new, l_new, acc), None
 
     m0 = jnp.full((b, hkv, g, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, sq), jnp.float32)
-    a0 = jnp.zeros((b, hkv, g, sq, d), COMPUTE_DTYPE)
+    a0 = jnp.zeros((b, hkv, g, sq, d), cd)
     (m_f, l_f, acc), _ = jax.lax.scan(
         body, (m0, l0, a0), (jnp.arange(n_chunks), kc, vc)
     )
     denom = jnp.where(l_f > 0, l_f, 1.0)[..., None]
-    out = (acc.astype(jnp.float32) / denom).astype(COMPUTE_DTYPE)
+    out = (acc.astype(jnp.float32) / denom).astype(cd)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d)  # (B,Sq,H,D)
 
 
@@ -296,8 +302,8 @@ def embedding_schema(vocab: int, d: int, *, tie: bool):
 def embed(p, tokens):
     # optimization_barrier pins the table convert BEFORE the gather: without
     # it XLA converts after the gather and the vocab-shard partial-sum
-    # all-reduce of the (B, S, D) activations runs in fp32 (2x bytes;
-    # EXPERIMENTS.md §Perf pair B).  JAX batches and differentiates the
+    # all-reduce of the (B, S, D) activations runs in fp32 (2x bytes).
+    # JAX batches and differentiates the
     # barrier itself (the FL engine vmaps and grads through this forward).
     table = jax.lax.optimization_barrier(p["tokens"].astype(COMPUTE_DTYPE))
     return constrain(table[tokens], "residual")
@@ -318,7 +324,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array, *, vocab_size: int):
     Written sharding-aware: the gold logit is extracted with an iota-match
     contraction rather than take_along_axis — a vocab-dim gather forces SPMD
     to all-gather the full (B, S, V) fp32 logits across the tensor axis
-    (74 GiB/step on qwen2-0.5b/train_4k; EXPERIMENTS.md §Perf iteration 0),
+    (74 GiB/step on qwen2-0.5b/train_4k in the dry-run),
     while the contraction reduces shard-locally and all-reduces only (B, S).
     """
     logits = logits.astype(jnp.float32)

@@ -33,7 +33,8 @@ import numpy as np
 class ParamSpec:
     shape: tuple
     axes: tuple                     # logical names, len == len(shape)
-    init: str = "normal"            # normal | zeros | ones | embed | ssm_a
+    init: str = "normal"            # normal | zeros | ones | embed | ssm_a |
+                                    # ssm_dt | lora_a
     scale: Optional[float] = None   # stddev override for "normal"
     dtype: str = "float32"
 
@@ -55,6 +56,17 @@ def _materialize(spec: ParamSpec, key) -> jax.Array:
         # Mamba2 A init: -uniform(1, 16) stored as log for stability.
         u = jax.random.uniform(key, spec.shape, jnp.float32, 1.0, 16.0)
         return jnp.log(u).astype(dtype)
+    if spec.init == "ssm_dt":
+        # Mamba2 dt bias: softplus^-1 of dt ~ log-uniform on [1e-3, 1e-1]
+        u = jax.random.uniform(key, spec.shape, jnp.float32)
+        dt = jnp.exp(u * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    if spec.init == "lora_a":
+        # LoRA's A (arXiv:2106.09685, as PEFT sets it): uniform within
+        # +-1/sqrt(fan_in)
+        bound = 1.0 / np.sqrt(max(_fan_in(spec.shape), 1))
+        return jax.random.uniform(key, spec.shape, jnp.float32, -bound,
+                                  bound).astype(dtype)
     if spec.init == "embed":
         std = spec.scale if spec.scale is not None else 0.02
         return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)

@@ -7,7 +7,8 @@ from typing import Any, Callable, Optional
 import jax
 
 from repro.config import ModelConfig
-from repro.models import encdec, hybrid, mamba2, moe, transformer, vlm
+from repro.models import (encdec, hybrid, mamba2, moe, pattern_hybrid,
+                          transformer, vlm)
 from repro.models.params import abstract_params, init_params, logical_specs
 
 _FAMILIES = {
@@ -15,6 +16,7 @@ _FAMILIES = {
     "moe": moe,
     "ssm": mamba2,
     "hybrid": hybrid,
+    "pattern": pattern_hybrid,
     "encdec": encdec,
     "vlm": vlm,
 }
