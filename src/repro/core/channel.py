@@ -51,13 +51,22 @@ class CellConfig:
         return n0_w_per_hz * self.bandwidth_hz
 
 
-def sample_positions(key: jax.Array, cfg: CellConfig) -> jax.Array:
-    """Uniformly distribute devices in the cell disk. Returns distances (M,)."""
+def position_uniforms(key: jax.Array, num_devices: int) -> jax.Array:
+    """The (M,) uniform draws behind :func:`sample_positions`."""
     k1, _ = jax.random.split(key)
-    # Uniform over the disk => CDF(r) = r^2 / R^2 => r = R * sqrt(u).
-    u = jax.random.uniform(k1, (cfg.num_devices,))
+    return jax.random.uniform(k1, (num_devices,))
+
+
+def distances_from_uniforms(u: jax.Array, cfg: CellConfig) -> jax.Array:
+    """Uniform over the disk => CDF(r) = r^2 / R^2 => r = R * sqrt(u)."""
     r = cfg.cell_radius_m * jnp.sqrt(u)
     return jnp.maximum(r, cfg.min_distance_m)
+
+
+def sample_positions(key: jax.Array, cfg: CellConfig) -> jax.Array:
+    """Uniformly distribute devices in the cell disk. Returns distances (M,)."""
+    return distances_from_uniforms(position_uniforms(key, cfg.num_devices),
+                                   cfg)
 
 
 def large_scale_gain(distances_m: jax.Array, cfg: CellConfig) -> jax.Array:
@@ -67,12 +76,23 @@ def large_scale_gain(distances_m: jax.Array, cfg: CellConfig) -> jax.Array:
     return num / den
 
 
+def fading_normals(key: jax.Array, shape) -> tuple:
+    """The standard normal (re, im) draws behind :func:`sample_small_scale`."""
+    kr, ki = jax.random.split(key)
+    return jax.random.normal(kr, shape), jax.random.normal(ki, shape)
+
+
+def rayleigh_magnitude(re: jax.Array, im: jax.Array) -> jax.Array:
+    """|h0| of h0 = (re + j im) / sqrt(2) from standard normal draws."""
+    half = jnp.sqrt(0.5)
+    re = re * half
+    im = im * half
+    return jnp.sqrt(re**2 + im**2)
+
+
 def sample_small_scale(key: jax.Array, shape) -> jax.Array:
     """|h0| with h0 ~ CN(0,1) (Rayleigh magnitude, E[|h0|^2] = 1)."""
-    kr, ki = jax.random.split(key)
-    re = jax.random.normal(kr, shape) * jnp.sqrt(0.5)
-    im = jax.random.normal(ki, shape) * jnp.sqrt(0.5)
-    return jnp.sqrt(re**2 + im**2)
+    return rayleigh_magnitude(*fading_normals(key, shape))
 
 
 def sample_channel_gains(
@@ -92,6 +112,15 @@ def sample_round_channels(
     return jax.vmap(sample_channel_gains, in_axes=(0, None, None))(
         keys, distances_m, cfg
     )
+
+
+def round_fading_normals(
+    key: jax.Array, num_devices: int, num_rounds: int
+) -> tuple:
+    """The (T, M) normal pairs behind :func:`sample_round_channels`: its
+    gains are ``large_scale_gain(d) * rayleigh_magnitude(re, im)``."""
+    keys = jax.random.split(key, num_rounds)
+    return jax.vmap(fading_normals, in_axes=(0, None))(keys, (num_devices,))
 
 
 def downlink_time_seconds(

@@ -339,10 +339,9 @@ def run_federated_learning(
             dataset, shards, cell, cfg, uplink=uplink, schedule=schedule,
             eval_every=eval_every, progress=progress,
         )
-    key = jax.random.PRNGKey(cfg.seed)
     model = get_fl_model(cfg.model)
-    params = model.init(key)
-    payload = tree_count(params) * 32  # I: full-precision payload bits
+    draws = _instance_draws(cfg.seed, cell, model, cfg.num_rounds)
+    params, payload, gains = draws.params0, draws.payload, draws.gains
     base = frozen_base(model)
 
     sizes = np.array([len(s) for s in shards], dtype=np.float64)
@@ -356,13 +355,6 @@ def run_federated_learning(
         engine = fl_engine.BatchedRoundEngine(
             dataset, shards, cfg, payload, model=model
         )
-
-    # channel realizations for the whole horizon
-    dist = chan.sample_positions(jax.random.fold_in(key, 1), cell)
-    gains = np.asarray(
-        chan.sample_round_channels(jax.random.fold_in(key, 2), dist, cell,
-                                   cfg.num_rounds)
-    )
 
     # Scheduling: precomputed policies (and caller-supplied schedules) fix
     # the whole horizon now; online policies run live inside the round loop.
@@ -388,20 +380,11 @@ def run_federated_learning(
         # hand-rolled revisiting schedule) decides C1 strictness.
         schedule.validate(cell.num_devices, cfg.group_size)
 
-    # Downlink broadcast time on the large-scale gain only: the paper's
-    # Fig. 5 time scale (35 rounds in ~10-22 s) implies a fading-free
-    # downlink; with per-round Rayleigh draws the worst faded user's T_d
-    # dominates both schemes and masks the NOMA/TDMA uplink gap.
-    dl_gains = chan.large_scale_gain(dist, cell)
-    dl_time = float(chan.downlink_time_seconds(payload, dl_gains, cell))
-
-    # OTA receiver-noise keys for the whole horizon — the same host
-    # precompute the scanned driver packs, so the two drivers draw
-    # bit-identical noise per round
-    ota_keys = (
-        ota_lib.horizon_keys(cfg.seed, cfg.num_rounds)
-        if uplink == "ota" else None
-    )
+    dl_time = draws.dl_time
+    # OTA receiver-noise keys for the whole horizon — the same draws the
+    # scanned driver packs, so the two drivers draw bit-identical noise
+    # per round
+    ota_keys = draws.noise_keys if uplink == "ota" else None
 
     if engine is None:   # the batched engine evaluates through its EvalBank
         x_test = jnp.asarray(dataset.x_test)
@@ -483,6 +466,69 @@ def run_federated_learning(
 
 
 # --------------------------------------------------------------------------
+# An instance's random draws: one program, one read back
+# --------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("num_devices", "num_rounds"))
+def _draw_program(seeds, *, num_devices, num_rounds):
+    """The random draws of one instance's cell, from ``PRNGKey(seeds[0])``:
+    the position uniforms (fold 1) and the (T, M) fading normals (fold 2);
+    and the OTA noise keys from ``PRNGKey(seeds[1])``.  The seeds are
+    operands and no cell scalar enters, so every seed and every cell of M
+    devices share one program."""
+    key = jax.random.PRNGKey(seeds[0])
+    u = chan.position_uniforms(jax.random.fold_in(key, 1), num_devices)
+    re, im = chan.round_fading_normals(
+        jax.random.fold_in(key, 2), num_devices, num_rounds
+    )
+    return u, re, im, ota_lib.round_keys(seeds[1], num_rounds)
+
+
+@dataclasses.dataclass
+class _InstanceDraws:
+    params0: dict                # initial parameters, on the device
+    payload: int                 # I: full-precision payload bits
+    gains: np.ndarray            # (T, M) channel amplitudes h = L |h0|
+    dl_time: float               # downlink broadcast seconds per round
+    noise_keys: np.ndarray       # (T, 2) uint32 OTA receiver-noise keys
+
+
+def _instance_draws(seed: int, cell, model, num_rounds: int) -> _InstanceDraws:
+    """The random draws of the instance ``seed``: the one owner every
+    driver plans from, so all of them simulate the identical system.
+
+    The cell's draws are one program (:func:`_draw_program`).  The float
+    arithmetic after them (distances, L(d), |h0|, h = L |h0|) stays the
+    eager op sequence of ``chan.sample_positions`` and
+    ``chan.sample_round_channels`` on whole arrays: fused into the
+    program it may round differently by an ulp, and the plans are held
+    exactly to a reference that draws eagerly.  One read brings home the
+    gains, the large-scale gains and the keys (counter
+    ``plan.device_reads``).  The model's init stays eager too: under jit
+    XLA folds an initializer's constant arithmetic on the host (the
+    truncated normal's erf(+-3 / sqrt 2)), an ulp off what the TPU
+    computes.
+    """
+    seed = int(seed)
+    u, re, im, keys = _draw_program(
+        np.array([seed, seed + ota_lib.OTA_SEED_OFFSET], np.int64),
+        num_devices=cell.num_devices, num_rounds=num_rounds,
+    )
+    ls = chan.large_scale_gain(chan.distances_from_uniforms(u, cell), cell)
+    gains = ls * chan.rayleigh_magnitude(re, im)
+    gains, ls, keys = jax.device_get((gains, ls, keys))
+    spans.count("plan.device_reads")
+    params = model.init(jax.random.PRNGKey(seed))
+    payload = tree_count(params) * 32
+    # Downlink broadcast time on the large-scale gain only: the paper's
+    # Fig. 5 time scale (35 rounds in ~10-22 s) implies a fading-free
+    # downlink; with per-round Rayleigh draws the worst faded user's T_d
+    # dominates both schemes and masks the NOMA/TDMA uplink gap.
+    dl_time = float(chan.downlink_time_seconds(payload, ls, cell))
+    return _InstanceDraws(params, payload, gains, dl_time, keys)
+
+
+# --------------------------------------------------------------------------
 # Scanned horizons: the whole precomputed simulation as ONE device program
 # --------------------------------------------------------------------------
 
@@ -528,24 +574,18 @@ class _HorizonPlan:
 def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
     """Host precompute for one scanned instance.
 
-    Mirrors :func:`run_federated_learning`'s setup exactly — same PRNG
-    folds, same schedule construction, same :func:`_round_physics` /
-    :func:`_agg_weights` calls — so the two drivers simulate the identical
-    system and the equality grid can demand identical schedules, budgets,
-    rates and times.
+    Mirrors :func:`run_federated_learning`'s setup exactly — the same
+    :func:`_instance_draws`, schedule construction, :func:`_round_physics`
+    and :func:`_agg_weights` calls — so the two drivers simulate the
+    identical system and the equality grid can demand identical schedules,
+    budgets, rates and times.
     """
-    key = jax.random.PRNGKey(cfg.seed)
-    params = get_fl_model(cfg.model).init(key)
-    payload = tree_count(params) * 32
+    draws = _instance_draws(cfg.seed, cell, get_fl_model(cfg.model),
+                            cfg.num_rounds)
+    gains = draws.gains
 
     sizes = np.array([len(s) for s in shards], dtype=np.float64)
     weights = sizes / sizes.sum()
-
-    dist = chan.sample_positions(jax.random.fold_in(key, 1), cell)
-    gains = np.asarray(
-        chan.sample_round_channels(jax.random.fold_in(key, 2), dist, cell,
-                                   cfg.num_rounds)
-    )
 
     if schedule is None:
         policy = scheduling.get_policy(cfg.scheduler)
@@ -563,9 +603,6 @@ def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
     else:
         schedule.validate(cell.num_devices, cfg.group_size)
 
-    dl_gains = chan.large_scale_gain(dist, cell)
-    dl_time = float(chan.downlink_time_seconds(payload, dl_gains, cell))
-
     T, K = cfg.num_rounds, cfg.group_size
     dev_tk = np.zeros((T, K), np.int32)
     ksizes = np.zeros(T, np.intp)
@@ -579,7 +616,7 @@ def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
         devs = schedule.rounds[t]
         rates, budgets, round_time = _round_physics(
             devs, schedule.powers[t], schedule.rates[t], t, gains, cell,
-            uplink, dl_time,
+            uplink, draws.dl_time,
         )
         k = len(devs)
         ksizes[t] = k
@@ -591,17 +628,14 @@ def _horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink, schedule):
         t_wall += round_time
         times[t] = t_wall
 
-    # the same per-round noise keys the per-round driver folds on the host
-    # (zeros are never consumed outside the OTA uplink, but packing them
-    # unconditionally keeps the plan shape uplink-independent)
-    noise_keys = ota_lib.horizon_keys(cfg.seed, T)
-
     eval_idx = eval_sample_plan(
         len(dataset.y_test), cfg.eval_sample, T, cfg.seed
     )
-    return _HorizonPlan(params, payload, schedule, dev_tk, ksizes,
-                        budgets_tk, aggw_tk, gains_tk, noise_keys,
-                        rates_list, times, eval_idx)
+    # the noise keys are packed under every uplink (only OTA consumes
+    # them): the plan's shape stays uplink-independent
+    return _HorizonPlan(draws.params0, draws.payload, schedule, dev_tk,
+                        ksizes, budgets_tk, aggw_tk, gains_tk,
+                        draws.noise_keys, rates_list, times, eval_idx)
 
 
 def _horizon_statics(
@@ -863,37 +897,29 @@ def _online_statics(cfg: FLConfig, cell, uplink, policy) -> dict:
 def _online_horizon_setup(dataset, shards, cell, cfg: FLConfig, uplink):
     """Host precompute for one online scanned instance.
 
-    Mirrors :func:`run_federated_learning`'s setup exactly — same PRNG
-    folds, same downlink model — and asks the policy's ``init_traced``
+    Mirrors :func:`run_federated_learning`'s setup exactly — the same
+    :func:`_instance_draws` — and asks the policy's ``init_traced``
     for its host aux (the f64-computed, f32-cast solo-rate table), so the
     traced selection ranks the same numbers the per-round driver's
     ``select_round`` does.
     """
-    key = jax.random.PRNGKey(cfg.seed)
-    params = get_fl_model(cfg.model).init(key)
-    payload = tree_count(params) * 32
+    draws = _instance_draws(cfg.seed, cell, get_fl_model(cfg.model),
+                            cfg.num_rounds)
 
     sizes = np.array([len(s) for s in shards], dtype=np.float64)
     weights = sizes / sizes.sum()
 
-    dist = chan.sample_positions(jax.random.fold_in(key, 1), cell)
-    gains = np.asarray(
-        chan.sample_round_channels(jax.random.fold_in(key, 2), dist, cell,
-                                   cfg.num_rounds)
-    )
-
     with spans.span("fl.schedule"):
         policy = scheduling.get_policy(cfg.scheduler)
-        aux = policy.init_traced(gains, weights, policy_config(cell, cfg))
+        aux = policy.init_traced(draws.gains, weights,
+                                 policy_config(cell, cfg))
 
-    dl_gains = chan.large_scale_gain(dist, cell)
-    dl_time = float(chan.downlink_time_seconds(payload, dl_gains, cell))
-    noise_keys = ota_lib.horizon_keys(cfg.seed, cfg.num_rounds)
     eval_idx = eval_sample_plan(
         len(dataset.y_test), cfg.eval_sample, cfg.num_rounds, cfg.seed
     )
-    return _OnlinePlan(params, payload, gains, weights, sizes, aux["solo"],
-                       noise_keys, dl_time, eval_idx)
+    return _OnlinePlan(draws.params0, draws.payload, draws.gains, weights,
+                       sizes, aux["solo"], draws.noise_keys, draws.dl_time,
+                       eval_idx)
 
 
 def _finalize_online_plan(

@@ -80,17 +80,29 @@ def check_uplink(uplink: str, *, compression: str, topk: float,
         raise ValueError(errors.ERR_OTA_ALIGN_UPLINK)
 
 
+def round_keys(seed: jax.Array, num_rounds: int) -> jax.Array:
+    """(T, 2) uint32 ``fold_in(PRNGKey(seed), t)`` for t < T, traceable:
+    ``seed`` may be a traced integer (``jax.random.PRNGKey`` maps it to
+    the key a Python int of the same value gives)."""
+    base = jax.random.PRNGKey(seed)
+    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        base, jnp.arange(num_rounds)
+    )
+
+
+_round_keys = jax.jit(round_keys, static_argnums=1)
+
+
 def horizon_keys(seed: int, num_rounds: int) -> np.ndarray:
     """(T, 2) uint32 per-round receiver-noise keys.
 
-    ``fold_in(PRNGKey(seed + OTA_SEED_OFFSET), t)`` on the host — threefry
-    is deterministic, so the per-round driver (indexing row t) and the
-    scanned horizon (consuming the stack as scan inputs) draw bit-identical
-    noise.
+    ``fold_in(PRNGKey(seed + OTA_SEED_OFFSET), t)``, one program and one
+    read back — threefry is deterministic, so the per-round driver
+    (indexing row t) and the scanned horizon (consuming the stack as scan
+    inputs) draw bit-identical noise.
     """
-    base = jax.random.PRNGKey(int(seed) + OTA_SEED_OFFSET)
-    return np.stack(
-        [np.asarray(jax.random.fold_in(base, t)) for t in range(num_rounds)]
+    return np.asarray(
+        _round_keys(np.int64(int(seed) + OTA_SEED_OFFSET), num_rounds)
     )
 
 

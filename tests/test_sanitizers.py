@@ -191,3 +191,31 @@ def test_nan_guard_disabled_passes_nans_through():
     with nan_guard(enable=False):
         out = jnp.divide(jnp.zeros(()), jnp.zeros(()))
     assert np.isnan(np.asarray(out))
+
+
+# --------------------------------------------------------------------------
+# an instance's draws: one program per (model, M, T), whatever the seed/cell
+# --------------------------------------------------------------------------
+
+def test_draw_program_compiles_once_across_seeds_and_cells():
+    """The seeds are integer operands and no cell scalar enters the draw
+    program, so seeds (above 2**31 too) and cells of equal M reuse it; the
+    eager arithmetic after it takes the cell's scalars as operands too."""
+    from repro.models.fl_models import get_fl_model
+
+    model = get_fl_model("lenet")
+    cells = (
+        channel.CellConfig(num_devices=M),
+        channel.CellConfig(num_devices=M, cell_radius_m=250.0,
+                           carrier_hz=5.8e9, antenna_gain=2.0,
+                           path_loss_exp=3.5),
+    )
+    fl._instance_draws(1, cells[0], model, 4)   # warm (model, M, T=4)
+    with compile_count() as tally:
+        for cell in cells:
+            for seed in (2, 9100011, 2300000011, 3100000024):
+                fl._instance_draws(seed, cell, model, 4)
+    assert tally.count == 0, f"draws recompiled {tally.count} programs"
+    with compile_count() as tally:
+        fl._instance_draws(2, cells[0], model, 13)   # a new T: a new shape
+    assert tally.count > 0
