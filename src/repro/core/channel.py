@@ -95,32 +95,21 @@ def sample_small_scale(key: jax.Array, shape) -> jax.Array:
     return rayleigh_magnitude(*fading_normals(key, shape))
 
 
-def sample_channel_gains(
-    key: jax.Array, distances_m: jax.Array, cfg: CellConfig
-) -> jax.Array:
-    """Per-device amplitude channel gain h_k = L_k * |h0| for one round."""
-    ls = large_scale_gain(distances_m, cfg)
-    ss = sample_small_scale(key, distances_m.shape)
-    return ls * ss
+def round_fading_normals(
+    key: jax.Array, num_devices: int, num_rounds: int
+) -> tuple:
+    """The (T, M) normal pairs behind :func:`sample_round_channels`."""
+    keys = jax.random.split(key, num_rounds)
+    return jax.vmap(fading_normals, in_axes=(0, None))(keys, (num_devices,))
 
 
 def sample_round_channels(
     key: jax.Array, distances_m: jax.Array, cfg: CellConfig, num_rounds: int
 ) -> jax.Array:
-    """Channel gains for every round: (T, M). Block fading across rounds."""
-    keys = jax.random.split(key, num_rounds)
-    return jax.vmap(sample_channel_gains, in_axes=(0, None, None))(
-        keys, distances_m, cfg
-    )
-
-
-def round_fading_normals(
-    key: jax.Array, num_devices: int, num_rounds: int
-) -> tuple:
-    """The (T, M) normal pairs behind :func:`sample_round_channels`: its
-    gains are ``large_scale_gain(d) * rayleigh_magnitude(re, im)``."""
-    keys = jax.random.split(key, num_rounds)
-    return jax.vmap(fading_normals, in_axes=(0, None))(keys, (num_devices,))
+    """Channel gains h = L(d) * |h0| for every round: (T, M). Block fading
+    across rounds."""
+    re, im = round_fading_normals(key, distances_m.shape[0], num_rounds)
+    return large_scale_gain(distances_m, cfg) * rayleigh_magnitude(re, im)
 
 
 def downlink_time_seconds(

@@ -2,6 +2,7 @@
 for bit the eager formulas the drivers ran before, and one read back per
 instance planned, whatever the driver."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,15 +19,27 @@ from repro.utils.tree import tree_count
 SEEDS = (7, 9100011, 2300000011, 3100000024)
 
 
+def _one_round_gains(key, dist, cell):
+    """One round's h = L(d) |h0|, as the channel module drew it per round."""
+    kr, ki = jax.random.split(key)
+    re = jax.random.normal(kr, dist.shape) * jnp.sqrt(0.5)
+    im = jax.random.normal(ki, dist.shape) * jnp.sqrt(0.5)
+    return chan.large_scale_gain(dist, cell) * jnp.sqrt(re**2 + im**2)
+
+
 def _eager_draws(seed, cell, model, num_rounds):
     """The draws as the drivers made them eagerly: the model's init, the
-    positions and the vmapped round channels, the noise keys folded and
-    read back one round at a time, the downlink on L(d)."""
+    positions and the round channels vmapped over per-round keys, the
+    noise keys folded and read back one round at a time, the downlink on
+    L(d)."""
     key = jax.random.PRNGKey(seed)
     params = model.init(key)
     dist = chan.sample_positions(jax.random.fold_in(key, 1), cell)
-    gains = np.asarray(chan.sample_round_channels(
-        jax.random.fold_in(key, 2), dist, cell, num_rounds))
+    round_keys = jax.random.split(jax.random.fold_in(key, 2), num_rounds)
+    gains = np.asarray(jax.vmap(_one_round_gains, in_axes=(0, None, None))(
+        round_keys, dist, cell))
+    np.testing.assert_array_equal(np.asarray(chan.sample_round_channels(
+        jax.random.fold_in(key, 2), dist, cell, num_rounds)), gains)
     base = jax.random.PRNGKey(seed + ota.OTA_SEED_OFFSET)
     keys = np.stack([np.asarray(jax.random.fold_in(base, t))
                      for t in range(num_rounds)])
